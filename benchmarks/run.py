@@ -20,6 +20,8 @@ import random
 import sys
 import traceback
 
+from repro.launch.cache import enable_compile_cache
+
 MODULES = [
     ("power", "benchmarks.power_prediction"),     # paper Fig. 2
     ("perf", "benchmarks.perf_prediction"),       # paper Fig. 3
@@ -60,6 +62,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0,
                     help="seed for python/numpy RNGs (default 0)")
     args = ap.parse_args()
+    enable_compile_cache()
     seed_everything(args.seed)
     want = set(args.only.split(",")) if args.only else None
     print("name,us_per_call,derived")

@@ -66,13 +66,26 @@ class KNNRegressor:
     def predict(self, X):
         X = jnp.log1p(jnp.abs(jnp.asarray(X, jnp.float32)))
         X = (X - self._mu) / self._sd
-        d2 = jnp.sum((X[:, None, :] - self._x[None, :, :]) ** 2, axis=-1)
-        k = min(self.k, self._x.shape[0])
+        pred = _knn_predict_jnp(self._x, self._y, X,
+                                k=min(self.k, self._x.shape[0]))
+        return np.asarray(jnp.exp(pred) if self.log_target else pred)
+
+
+# query rows per step of the KNN scan: the distance block is [KNN_BATCH,
+# n_train], so a design-space-sized query never holds [N, n_train, F]
+KNN_BATCH = 1024
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _knn_predict_jnp(x_train, y_train, X, *, k: int):
+    """Inverse-distance-weighted mean of the ``k`` nearest training targets
+    for each row of ``X`` (all inputs already z-scored), in row blocks."""
+    def one(row):
+        d2 = jnp.sum((row[None, :] - x_train) ** 2, axis=-1)
         neg_d2, idx = jax.lax.top_k(-d2, k)
         w = 1.0 / (jnp.sqrt(-neg_d2) + 1e-6)
-        w = w / jnp.sum(w, axis=1, keepdims=True)
-        pred = jnp.sum(w * self._y[idx], axis=1)
-        return np.asarray(jnp.exp(pred) if self.log_target else pred)
+        return jnp.sum(w / jnp.sum(w) * y_train[idx])
+    return jax.lax.map(one, X, batch_size=KNN_BATCH)
 
 
 # --- CART decision tree ------------------------------------------------------------------

@@ -607,7 +607,8 @@ class _WorkerPool:
         self.lost: set = set()
         self.duplicate_pending = fault.duplicate
         self.stats = {"deliveries": 0, "duplicates": 0, "reissued_tiles": 0,
-                      "lost_workers": [], "n_workers": int(n_workers)}
+                      "lost_workers": [], "n_workers": int(n_workers),
+                      "worker_backends": {}}
         for w in range(n_workers):
             worker_cfg = {}
             if fault.kill_worker == w:
@@ -629,6 +630,7 @@ class _WorkerPool:
             if kind == "ready":
                 ready.add(w)
                 self.idle.append(w)
+                self.stats["worker_backends"][w] = payload
             elif kind == "error":
                 raise RuntimeError(f"adaptive worker {w} failed: {payload}")
             self._reap()

@@ -793,12 +793,32 @@ class LocalFabric:
 # multiprocess fabric (real workers, spawn)
 # ---------------------------------------------------------------------------
 
+def _pin_worker_to_cpu() -> str:
+    """Put this worker process on JAX's CPU backend; returns the backend.
+
+    Workers are host processes.  An accelerator belongs to one process at a
+    time — the one that started the campaign — and a second process that
+    reaches for it fails or hangs.  A spawned child inherits the parent's
+    environment (on a TPU host, JAX picks the TPU by default), so the worker
+    pins the CPU before anything initializes a backend.  A worker whose
+    backend is already up on another platform refuses to start."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(f"fabric worker started on the {backend!r} "
+                           "backend; workers must run on the CPU")
+    return backend
+
+
 def _worker_main(worker_id: int, cfg: Dict, worker_cfg: Dict,
                  task_q, result_q) -> None:
-    """Fabric worker loop (runs in a ``spawn`` child).
+    """Fabric worker loop (runs in a ``spawn`` child, on the CPU backend —
+    see ``_pin_worker_to_cpu``).
 
     Protocol (all messages are 5-tuples ``(kind, wid, tile, payload,
-    busy_s)``): emits ``("ready", ...)`` once warm, then for each leased
+    busy_s)``): emits ``("ready", wid, None, backend, 0.0)`` once warm,
+    then for each leased
     tile received on ``task_q`` evaluates it with the shared
     ``TileEvaluator`` and emits ``("result", wid, tile, TileReduction,
     busy_s)``; ``None`` on ``task_q`` is shutdown, answered with a terminal
@@ -813,6 +833,7 @@ def _worker_main(worker_id: int, cfg: Dict, worker_cfg: Dict,
     compile cost.
     """
     try:
+        backend = _pin_worker_to_cpu()
         evaluator = evaluator_from_config(cfg)
         tel = evaluator.telemetry
         c_busy = tel.counter("worker_busy_s_total")
@@ -822,7 +843,7 @@ def _worker_main(worker_id: int, cfg: Dict, worker_cfg: Dict,
             lo, hi = tile_span(space, 0)
             evaluator.reduce_tile(space.slice(lo, hi, with_candidates=False),
                                   lo)
-        result_q.put(("ready", worker_id, None, None, 0.0))
+        result_q.put(("ready", worker_id, None, backend, 0.0))
         die_on_nth = (worker_cfg or {}).get("die_on_nth_tile")
         die_on_tile = (worker_cfg or {}).get("die_on_tile")
         n_received = 0
@@ -927,6 +948,7 @@ class MultiprocessFabric:
         procs: Dict[int, mp.Process] = {}
         task_qs: Dict[int, object] = {}
         busy_s: Dict[int, float] = {}
+        backends: Dict[int, str] = {}
         worker_metrics: Dict[int, Dict] = {}
         idle: List[int] = []
         ready: set = set()
@@ -998,6 +1020,7 @@ class MultiprocessFabric:
                     coord.register_worker(w)
                     idle.append(w)
                     ready.add(w)
+                    backends[w] = payload
                     if len(ready | lost) >= self.n_workers:
                         if window_t0 is None:
                             window_t0 = clock()
@@ -1097,6 +1120,7 @@ class MultiprocessFabric:
             "total_busy_s": sum(busy_final.values()),
             "window_s": window_s,
             "worker_metrics": worker_metrics,
+            "worker_backends": backends,
         }
         return coord.result(window_s)
 

@@ -10,13 +10,17 @@ constraint mask (``costmodel.sweep_feasibility``), for every cached workload
 in one ``pallas_call``.
 
 Layout: candidates arrive as one packed [len(CAND_COLS), N] column matrix
-(lane-padded to 128, padding lanes carry ``valid=0``); per-workload scalars
-as the packed [W, len(WL_COLS)] matrix, broadcast as a leading data axis
+(padding lanes carry ``valid=0``); per-workload scalars as the packed
+[W, len(WL_COLS)] matrix, broadcast as a leading data axis
 ([W, 1] x [1, N] -> [W, N]) so the kernel body is W-independent — all
 elementwise VPU math, no gathers, no host round-trips between workloads.
+The launch is a 1-D grid over lane blocks (``block_lanes``): each step
+streams one [len(CAND_COLS), B] candidate block and writes three [W, B]
+output blocks, so VMEM holds one block whatever the tile size and the
+HBM<->VMEM copies are pipelined across steps.
 
 Precision tiers: in interpret mode (CPU CI / debugging) the whole sweep runs
-float64 under a scoped ``jax.experimental.enable_x64`` so the resulting
+float64 under a scoped ``jax.enable_x64(True)`` so the resulting
 frontier holds the float64 numpy evaluator's exact candidate set (values
 agree to ~1 ulp — XLA fusion noise only); compiled on an accelerator it
 runs float32 (the same tier as ``simulate_batch_jit``, ~1e-6 relative).
@@ -47,6 +51,17 @@ CAND_COLS = ("n_chips", "freq_mhz", "mesh_pod", "mesh_data", "mesh_model",
              "valid") + costmodel.SWEEP_GATHER_FIELDS
 
 LANE = 128   # TPU lane width; candidate tiles are padded to a multiple
+# [W, B] elements one grid step computes: bounds the kernel's VMEM working set
+# (the body keeps a few dozen [W, B] float32 temporaries) independently of W
+BLOCK_ELEMS = 6 * 4096
+MAX_BLOCK_LANES = 4096
+
+
+def block_lanes(n_workloads: int) -> int:
+    """Lanes per grid step for ``n_workloads`` rows: ``MAX_BLOCK_LANES``
+    for up to six workloads, fewer (in ``LANE`` steps) for more."""
+    per_row = BLOCK_ELEMS // max(int(n_workloads), 1)
+    return max(LANE, min(MAX_BLOCK_LANES, per_row // LANE * LANE))
 
 
 def _sweep_kernel(wl_ref, cand_ref, e_ref, l_ref, f_ref, *,
@@ -81,21 +96,32 @@ def dse_sweep_pallas(cand_cols, wl_cols, *, sim: costmodel.SimConfig,
     """Raw kernel launch: (energy, latency, feasible) as [W, N] arrays.
 
     ``cand_cols`` is the packed [len(CAND_COLS), N] candidate matrix with N a
-    multiple of ``LANE``; ``wl_cols`` the [W, len(WL_COLS)] workload matrix.
+    multiple of ``block_lanes(W)`` (``_pad_lanes`` pads to it); ``wl_cols``
+    the [W, len(WL_COLS)] workload matrix, resident in every grid step.
     """
     ncol, n = cand_cols.shape
     if ncol != len(CAND_COLS):
         raise ValueError(f"cand_cols must be [{len(CAND_COLS)}, N] "
                          f"({CAND_COLS}), got {cand_cols.shape}")
     w_count = wl_cols.shape[0]
+    block = min(block_lanes(w_count), n)
+    if n % block:
+        raise ValueError(f"lane count {n} is not a multiple of the "
+                         f"{block}-lane block")
     kernel = functools.partial(
         _sweep_kernel, sim=sim, max_power_w=max_power_w,
         max_latency_s=max_latency_s, min_hbm_fit=min_hbm_fit)
     dt = cand_cols.dtype
+    out_spec = pl.BlockSpec((w_count, block), lambda i: (0, i))
     return pl.pallas_call(
         kernel,
+        grid=(n // block,),
+        in_specs=[pl.BlockSpec(wl_cols.shape, lambda i: (0, 0)),
+                  pl.BlockSpec((ncol, block), lambda i: (0, i))],
+        out_specs=[out_spec] * 3,
         out_shape=[jax.ShapeDtypeStruct((w_count, n), dt)] * 3,
         interpret=interpret,
+        name="dse_sweep",
     )(wl_cols, cand_cols)
 
 
@@ -118,11 +144,20 @@ def pack_cand_cols(arrays: dict, dtype=np.float64) -> np.ndarray:
     return np.stack([np.asarray(arrays[k], dtype) for k in CAND_COLS])
 
 
-def _pad_lanes(cand_cols: np.ndarray, n_valid: int) -> np.ndarray:
-    """Right-pad the lane axis to a ``LANE`` multiple; padding lanes copy
-    lane 0 (safe arithmetic — no zero divides) with ``valid`` forced to 0."""
-    n = cand_cols.shape[1]
+def padded_lanes(n: int, n_workloads: int) -> int:
+    """The lane count a tile of ``n`` candidates is padded to: a ``LANE``
+    multiple, and past one block a ``block_lanes(n_workloads)`` multiple."""
     target = -(-max(n, 1) // LANE) * LANE
+    block = block_lanes(n_workloads)
+    return target if target <= block else -(-target // block) * block
+
+
+def _pad_lanes(cand_cols: np.ndarray, n_valid: int,
+               n_workloads: int) -> np.ndarray:
+    """Right-pad the lane axis to ``padded_lanes``; padding lanes copy lane
+    0 (safe arithmetic — no zero divides) with ``valid`` forced to 0."""
+    n = cand_cols.shape[1]
+    target = padded_lanes(n, n_workloads)
     if n < target:
         fill = np.repeat(cand_cols[:, :1], target - n, axis=1)
         cand_cols = np.concatenate([cand_cols, fill], axis=1)
@@ -153,13 +188,13 @@ def dse_sweep_reduced(cand_cols: np.ndarray, wl_cols: np.ndarray, *,
     """
     n = cand_cols.shape[1]
     n_valid = n if n_valid is None else int(n_valid)
-    cand_cols = _pad_lanes(np.asarray(cand_cols, np.float64), n_valid)
     wl_cols = np.asarray(wl_cols, np.float64)
+    cand_cols = _pad_lanes(np.asarray(cand_cols, np.float64), n_valid,
+                           wl_cols.shape[0])
     fn = _jit_dse_sweep(sim, max_power_w, max_latency_s, bool(min_hbm_fit),
                         bool(interpret))
     if interpret:
-        import jax.experimental
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             out = fn(cand_cols, wl_cols)
     else:
         out = fn(cand_cols.astype(np.float32), wl_cols.astype(np.float32))

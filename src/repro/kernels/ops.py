@@ -2,17 +2,15 @@
 
 These are the entry points models/benchmarks/campaigns use; each handles
 layout (GQA head expansion, padding, column packing) and dispatches to the
-kernel.  ``interpret`` defaults to auto-detection from the active JAX
-backend (``default_interpret``): compiled on TPU, interpreted everywhere
-else, overridable per call (``interpret=`` kwarg) or per process
-(``REPRO_PALLAS_INTERPRET=0/1``).  Resolution happens BEFORE the jit
-boundary so the env override is honored even across cached traces.
+kernel.  ``interpret`` defaults to the active JAX backend
+(``default_interpret``): compiled on TPU, interpreted everywhere else; an
+explicit ``interpret=`` argument is the only override, so nothing in the
+environment can put a TPU run into the interpreter.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -27,17 +25,9 @@ from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
 def default_interpret() -> bool:
-    """Whether Pallas kernels should run in interpret mode by default.
-
-    Auto-detects from ``jax.default_backend()`` — compiled kernels on TPU,
-    interpret mode on CPU/GPU backends (this container is CPU-only, so CI
-    exercises interpret mode end to end).  The ``REPRO_PALLAS_INTERPRET``
-    env var overrides the detection; an explicit ``interpret=`` kwarg on any
-    wrapper overrides both.
-    """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "no", "off")
+    """Whether Pallas kernels run in interpret mode by default: compiled on
+    TPU, interpreted on CPU/GPU backends (CPU CI exercises interpret mode
+    end to end).  An explicit ``interpret=`` on a wrapper overrides it."""
     return jax.default_backend() != "tpu"
 
 

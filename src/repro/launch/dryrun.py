@@ -1,10 +1,11 @@
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware: builds the
-production mesh from 512 placeholder host devices, lowers the real train /
-serve step with ShapeDtypeStruct inputs (no allocation), compiles, and
-records memory_analysis / cost_analysis / the collective census (HxA) to a
-JSON artifact per cell under ``experiments/dryrun/``.
+production mesh from 512 placeholder host devices (``jax.devices("cpu")``,
+so the census runs the same on a machine that also holds a TPU), lowers the
+real train / serve step with ShapeDtypeStruct inputs (no allocation),
+compiles, and records memory_analysis / cost_analysis / the collective
+census (HxA) to a JSON artifact per cell under ``experiments/dryrun/``.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-14b --shape train_4k [--multi-pod]
@@ -24,11 +25,9 @@ import traceback         # noqa: E402
 import jax               # noqa: E402
 
 from repro.configs.base import ARCH_NAMES, SHAPES, get_config  # noqa: E402
+from repro.launch.cache import enable_compile_cache            # noqa: E402
 from repro.launch.mesh import make_production_mesh             # noqa: E402
 from repro.launch.lowering import lower_cell                   # noqa: E402
-
-ART_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                       "experiments", "dryrun")
 
 
 def art_dir() -> str:
@@ -39,10 +38,13 @@ def art_dir() -> str:
 
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool, save: bool = True,
-             overrides: dict | None = None) -> dict:
+             overrides: dict | None = None, out_dir: str | None = None) -> dict:
+    """Census one cell; with ``save`` the artifact goes to ``out_dir``
+    (default ``art_dir()``)."""
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
-    mesh = make_production_mesh(multi_pod=multi_pod)
+    mesh = make_production_mesh(multi_pod=multi_pod,
+                                devices=jax.devices("cpu"))
     t0 = time.time()
     save_hlo = os.environ.get("REPRO_SAVE_HLO", "1") == "1"
     result = lower_cell(cfg, shape, mesh, overrides=overrides or {},
@@ -55,14 +57,15 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, save: bool = True,
         tag = f"{arch}__{shape_name}__{'pod2' if multi_pod else 'pod1'}"
         if overrides:
             tag += "__" + "_".join(f"{k}-{v}" for k, v in sorted(overrides.items()))
+        out_dir = out_dir or art_dir()
         hlo_text = result.pop("hlo_text", None)
         if hlo_text is not None:
             import gzip
-            hdir = os.path.join(art_dir(), "hlo")
+            hdir = os.path.join(out_dir, "hlo")
             os.makedirs(hdir, exist_ok=True)
             with gzip.open(os.path.join(hdir, tag + ".hlo.gz"), "wt") as f:
                 f.write(hlo_text)
-        path = os.path.join(art_dir(), tag + ".json")
+        path = os.path.join(out_dir, tag + ".json")
         with open(path, "w") as f:
             json.dump(result, f, indent=1)
         print(f"[dryrun] wrote {path}")
@@ -134,6 +137,7 @@ def main():
                     help="override key=value (e.g. remat=none)")
     args = ap.parse_args()
     overrides = dict(kv.split("=", 1) for kv in args.set)
+    enable_compile_cache()
 
     cells = list(applicable_cells()) if args.all else [(args.arch, args.shape)]
     failures = []

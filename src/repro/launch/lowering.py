@@ -129,8 +129,7 @@ def _memory_dict(compiled) -> Dict:
 
 def _cost_dict(compiled) -> Dict:
     try:
-        from repro import compat
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
     except Exception:
         ca = {}
     return {"flops": float(ca.get("flops", 0.0)),

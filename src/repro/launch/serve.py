@@ -28,6 +28,8 @@ import json
 
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
+
 
 def serve(arch: str, n_requests: int = 8, slots: int = 4, max_len: int = 128,
           prompt_len: int = 8, max_new: int = 16, seed: int = 0):
@@ -68,11 +70,15 @@ def build_index(checkpoint: str, out: str) -> str:
     return path
 
 
-def select_queries(index_path: str, queries_path: str = None):
-    """Answer a batch of selection queries; returns the answers.
+def select_queries(index_path: str, queries_path: str = None,
+                   power_model=None, cycles_model=None):
+    """Answer a batch of selection queries; returns ``(answers, engine)``.
 
     All queries are submitted before one ``flush`` — the CLI batch IS the
-    batching window, so concurrent novel queries share one fused sweep.
+    batching window, so concurrent novel queries share one fused sweep
+    (``engine.fused_launches`` counts them).  Fitted ``power_model`` /
+    ``cycles_model`` predictors enable the predictor paths: deadline
+    degradation to ``predictor_only`` and top-slice pruning.
     """
     from repro.core import dse
     from repro.dse_campaign.runner import workload_from_dict
@@ -80,7 +86,8 @@ def select_queries(index_path: str, queries_path: str = None):
     from repro.serving.frontier_index import FrontierIndex
 
     index = FrontierIndex.load(index_path)
-    engine = SelectionEngine(index)
+    engine = SelectionEngine(index, SelectionEngine._config_from_index(
+        index).replace(power_model=power_model, cycles_model=cycles_model))
     if queries_path:
         with open(queries_path) as f:
             queries = json.load(f)
@@ -107,7 +114,7 @@ def select_queries(index_path: str, queries_path: str = None):
                       for p in ("index_exact", "mini_campaign",
                                 "predictor_only"))
           + f"; fused launches: {engine.fused_launches}")
-    return answers
+    return answers, engine
 
 
 def main():
@@ -124,6 +131,7 @@ def main():
     ap.add_argument("--index", help="select: FrontierIndex artifact")
     ap.add_argument("--queries", help="select: JSON query batch (optional)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "build-index":
         if not (args.checkpoint and args.out):
             ap.error("--mode build-index needs --checkpoint and --out")

@@ -21,6 +21,7 @@ import numpy as np
 from repro.checkpoint import store
 from repro.configs.base import ShapeConfig, get_config
 from repro.data.pipeline import DataConfig, DataIterator
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import api
 from repro.models.dist import make_dist
@@ -107,6 +108,7 @@ def main():
     ap.add_argument("--mesh", help="e.g. 2x4")
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    enable_compile_cache()
     mesh_shape = tuple(int(x) for x in args.mesh.split("x")) if args.mesh else None
     losses, _ = train(args.arch, steps=args.steps, reduced=args.reduced,
                       seq_len=args.seq_len, batch=args.batch,

@@ -26,7 +26,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models import layers as L
 
 
@@ -105,7 +104,7 @@ def moe_ep_local(p_local, cfg, x_loc, *, model_axis: str, fsdp_axis: Optional[st
     x_loc: [b, s, D] local batch shard (replicated over `model_axis`).
     p_local: expert weights sharded [E_loc, ...] over model (+ FSDP on D dim).
     """
-    n_groups = compat.axis_size(model_axis)
+    n_groups = jax.lax.axis_size(model_axis)
     g = jax.lax.axis_index(model_axis)
     E, k = cfg.num_experts, cfg.experts_per_token
     E_loc = E // n_groups
@@ -114,7 +113,7 @@ def moe_ep_local(p_local, cfg, x_loc, *, model_axis: str, fsdp_axis: Optional[st
     xf = x_loc.reshape(T, D)
 
     w_in, w_gate, w_out = p_local["w_in"], p_local["w_gate"], p_local["w_out"]
-    n_fsdp = compat.axis_size(fsdp_axis) if fsdp_axis is not None else 1
+    n_fsdp = jax.lax.axis_size(fsdp_axis) if fsdp_axis is not None else 1
     C_cap = _capacity(cfg, T)
     F = w_in.shape[-1]
     mode = cfg.moe_fsdp
@@ -180,7 +179,7 @@ def moe_ep_local(p_local, cfg, x_loc, *, model_axis: str, fsdp_axis: Optional[st
     if "shared" in p_local:
         # shared expert: F dim TP-sharded over `model`; D dim FSDP-gathered.
         ps = p_local["shared"]
-        if fsdp_axis is not None and compat.axis_size(fsdp_axis) > 1:
+        if fsdp_axis is not None and jax.lax.axis_size(fsdp_axis) > 1:
             ps = {"w_in": jax.lax.all_gather(ps["w_in"], fsdp_axis, axis=0, tiled=True),
                   "w_gate": jax.lax.all_gather(ps["w_gate"], fsdp_axis, axis=0, tiled=True),
                   "w_out": jax.lax.all_gather(ps["w_out"], fsdp_axis, axis=1, tiled=True)}
@@ -212,7 +211,7 @@ def moe_block(p, cfg, x, dist=None) -> tuple:
     dp_tuple = dp if isinstance(dp, tuple) else (dp,)
     fn = functools.partial(moe_ep_local, cfg=cfg, model_axis=mdl, fsdp_axis=fsdp,
                            dp_axes=dp_tuple)
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         lambda pp, xx: fn(pp, x_loc=xx),
         mesh=dist.mesh,
         in_specs=in_specs,

@@ -17,8 +17,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 BLOCK = 256
 
 
@@ -76,6 +74,6 @@ def crosspod_compressed_psum(grads: Any, residual: Any, mesh, pod_axis: str = "p
         return summed, new_e
 
     spec = jax.tree_util.tree_map(lambda _: P(), grads)
-    return compat.shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                            out_specs=(spec, spec),
-                            check_vma=False)(grads, residual)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=(spec, spec),
+                         check_vma=False)(grads, residual)

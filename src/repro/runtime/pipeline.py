@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def pipeline_apply(stage_fn: Callable, stage_params, x, mesh,
                    n_micro: int, stage_axis: str = "stage"):
@@ -59,7 +57,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, mesh,
 
     w_specs = jax.tree_util.tree_map(
         lambda a: P(stage_axis, *([None] * (a.ndim - 1))), stage_params)
-    out = compat.shard_map(body, mesh=mesh,
-                           in_specs=(w_specs, P()), out_specs=P(),
-                           check_vma=False)(stage_params, xm)
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(w_specs, P()), out_specs=P(),
+                        check_vma=False)(stage_params, xm)
     return out.reshape(B, *x.shape[1:])

@@ -293,3 +293,17 @@ def test_adaptive_distributed_matches_single_process(fault):
     if fault is not None:
         assert stats["lost_workers"] == [1]
         assert stats["reissued_tiles"] >= 1
+
+
+def test_adaptive_workers_run_on_cpu_under_tpu_parent(monkeypatch):
+    """The adaptive worker pool spawns the fabric's workers: with the
+    parent's environment selecting the TPU they still start on the CPU
+    backend, and the result equals the single-process run."""
+    cfg = campaign_cfg(adaptive_cfg(), n_workers=1)
+    single = AdaptiveCampaign([WL], cfg)
+    single.run()
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    dr, stats = run_adaptive_distributed([WL], cfg)
+    assert stats["worker_backends"] == {0: "cpu"}
+    assert stats["lost_workers"] == []
+    assert frontiers_identical(dr.frontiers[KEY], single.frontiers[KEY])

@@ -14,6 +14,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(body: str, timeout=900):
     code = "import os\n" \
            "os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'\n" \
+           "from repro.launch.mesh import make_mesh\n" \
            + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
@@ -31,7 +32,7 @@ def test_moe_ep_matches_dense():
 
     cfg = get_config('deepseek_v2_236b').reduced()
     cfg = dataclasses.replace(cfg, capacity_factor=8.0)  # no drops -> exact
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = make_mesh((2, 4), ('data', 'model'))
     dist = Dist(mesh=mesh, dp_axes=('data',))
     p = MOE.init_moe(jax.random.PRNGKey(0), cfg)
     x = (jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model)) * 0.1
@@ -61,7 +62,7 @@ def test_train_step_on_mesh_and_elastic_restore():
     opt = optim.make_optimizer(cfg.optimizer, total_steps=10)
 
     # --- train 2 steps on a 2x4 mesh
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = make_mesh((2, 4), ('data', 'model'))
     dist = make_dist(mesh)
     params = model.init(jax.random.PRNGKey(0), max_seq=32)
     params = jax.device_put(params, param_shardings(params, dist))
@@ -77,7 +78,7 @@ def test_train_step_on_mesh_and_elastic_restore():
     # --- checkpoint, restore onto a DIFFERENT mesh (4x2): elastic
     with tempfile.TemporaryDirectory() as d:
         store.save(d, 2, state.params)
-        mesh2 = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh2 = make_mesh((4, 2), ('data', 'model'))
         dist2 = make_dist(mesh2)
         shardings2 = param_shardings(state.params, dist2)
         _, params2, _ = store.restore(d, shardings=shardings2)
@@ -108,7 +109,7 @@ def test_losses_match_across_mesh_shapes():
     batch = {'tokens': jnp.arange(8 * 32).reshape(8, 32).astype(jnp.int32) % 64,
              'labels': jnp.arange(8 * 32).reshape(8, 32).astype(jnp.int32) % 64}
     loss_1dev, _ = jax.jit(lambda p, b: model.loss(p, b))(params, batch)
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = make_mesh((2, 4), ('data', 'model'))
     dist = make_dist(mesh)
     loss_mesh, _ = jax.jit(lambda p, b: model.loss(p, b, dist))(params, batch)
     assert abs(float(loss_1dev) - float(loss_mesh)) < 5e-2, \
@@ -122,7 +123,7 @@ def test_compressed_crosspod_psum():
     import jax, jax.numpy as jnp, numpy as np
     from repro.optim import compression
 
-    mesh = jax.make_mesh((2, 4), ('pod', 'data'))
+    mesh = make_mesh((2, 4), ('pod', 'data'))
     g = {'w': jnp.ones((64, 8), jnp.float32) * 0.01}
     e = compression.init_residual(g)
     summed, new_e = compression.crosspod_compressed_psum(g, e, mesh, 'pod')
@@ -150,7 +151,7 @@ def test_pipeline_parallel_stage_axis():
     for i in range(n_stage):
         h = stage_fn(ws[i], h)
 
-    mesh = jax.make_mesh((4,), ('stage',))
+    mesh = make_mesh((4,), ('stage',))
     out = pipeline_apply(stage_fn, ws, x, mesh, n_micro=micro)
     np.testing.assert_allclose(np.asarray(out), np.asarray(h), atol=1e-5)
     print('pipeline OK')

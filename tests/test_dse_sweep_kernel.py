@@ -269,6 +269,26 @@ def test_campaign_pallas_matches_numpy_frontier(chunk):
         assert hb == pytest.approx(ha, rel=1e-6)
 
 
+def test_campaign_pallas_multi_block_grid_matches_numpy(monkeypatch):
+    """A tile spanning several lane blocks of the kernel's grid (128-lane
+    blocks here, 320 candidates padded to three of them) still holds the
+    numpy evaluator's exact frontier candidate set."""
+    from repro.kernels import dse_sweep
+    monkeypatch.setattr(dse_sweep, "MAX_BLOCK_LANES", dse_sweep.LANE)
+    dse_sweep._jit_dse_sweep.cache_clear()
+    spec = small_spec(chip_counts=(16, 64), freq_points=20, chunk_size=320)
+    assert dse_sweep.padded_lanes(len(spec), len(WLS)) == 3 * dse_sweep.LANE
+    try:
+        a = Campaign(WLS, spec, constraint=CONS, evaluator="numpy").run()
+        b = Campaign(WLS, spec, constraint=CONS, evaluator="pallas").run()
+    finally:
+        dse_sweep._jit_dse_sweep.cache_clear()
+    for key in a.frontiers:
+        assert len(a.frontiers[key]) > 1
+        assert_same_candidate_set(a.frontiers[key], b.frontiers[key],
+                                  rtol=1e-12)
+
+
 def test_campaign_jit_fused_matches_numpy_candidate_set():
     """The float32 fused jit evaluator lands on the same frontier candidate
     set (values only to float32 tolerance)."""
@@ -412,14 +432,16 @@ def test_compare_evaluators_gates():
 
 
 def test_default_interpret_autodetect(monkeypatch):
+    """Interpret mode follows the backend alone: the environment cannot put
+    a TPU run into the interpreter, only an explicit ``interpret=`` can."""
     import jax
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
     expected = jax.default_backend() != "tpu"
     assert ops.default_interpret() is expected   # CPU container -> True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert ops.default_interpret() is False
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert ops.default_interpret() is True
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
-    assert ops._resolve_interpret(None) is expected
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.default_interpret() is False
+    assert ops._resolve_interpret(None) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert ops._resolve_interpret(None) is True
     assert ops._resolve_interpret(False) is False

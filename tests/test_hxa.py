@@ -66,8 +66,7 @@ def test_real_module_trip_aware_flops():
     x = jnp.zeros((8, 64), jnp.float32)
     comp = jax.jit(f).lower(w, x).compile()
     res = hxa.analyze_hlo_text(comp.as_text())
-    from repro import compat
-    xla_flops = compat.cost_analysis(comp)["flops"]
+    xla_flops = comp.cost_analysis()["flops"]
     per_iter = 2 * 8 * 64 * 64
     assert res["flops"] >= 9 * per_iter
     assert xla_flops < 2 * per_iter  # body counted once

@@ -67,6 +67,22 @@ def test_knn_k1_interpolates_training_points(n):
     np.testing.assert_allclose(pred, y, rtol=5e-3)
 
 
+def test_knn_row_blocks_match_direct_formula():
+    """Prediction in ``KNN_BATCH`` row blocks (with a partial last block)
+    equals the one-shot [N, n_train] distance formula."""
+    X, y = _synthetic(n=300)
+    m = P.KNNRegressor().fit(X, y)
+    Q = RNG.uniform(0.5, 4.0, (2 * P.KNN_BATCH + 37, X.shape[1]))
+    Qz = (np.log1p(np.abs(Q.astype(np.float32))) - np.asarray(m._mu)) \
+        / np.asarray(m._sd)
+    d2 = ((Qz[:, None, :] - np.asarray(m._x)[None]) ** 2).sum(-1)
+    near = np.argsort(d2, axis=1, kind="stable")[:, :m.k]
+    w = 1.0 / (np.sqrt(np.take_along_axis(d2, near, 1)) + 1e-6)
+    want = np.exp((w / w.sum(1, keepdims=True)
+                   * np.asarray(m._y)[near]).sum(1))
+    np.testing.assert_allclose(m.predict(Q), want, rtol=1e-4)
+
+
 @settings(max_examples=15, deadline=None)
 @given(st.floats(1.1, 3.0), st.floats(0.1, 0.9))
 def test_predictor_scale_monotonicity(a, b):

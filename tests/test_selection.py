@@ -333,15 +333,17 @@ def test_serve_cli_build_index_and_select(tmp_path, offline, capsys):
     ckpt = str(tmp_path / "ckpt.json")
     store.save_checkpoint(camp.state_dict(), ckpt)
     idx_path = build_index(ckpt, str(tmp_path / "index.json"))
-    answers = select_queries(idx_path)     # self-check: all families
+    answers, engine = select_queries(idx_path)   # self-check: all families
     assert [a.provenance for a in answers] == ["index_exact"] * len(CACHED)
+    assert engine.fused_launches == 0
     queries = [{"workload": workload_to_dict(CACHED[0])},
                {"workload": workload_to_dict(NOVEL), "deadline_s": 60.0}]
     qpath = tmp_path / "queries.json"
     qpath.write_text(json.dumps(queries))
-    answers = select_queries(idx_path, str(qpath))
+    answers, engine = select_queries(idx_path, str(qpath))
     assert [a.provenance for a in answers] == ["index_exact",
                                                "mini_campaign"]
+    assert engine.fused_launches == 1
     assert "fused launches" in capsys.readouterr().out
 
 
