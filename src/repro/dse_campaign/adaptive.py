@@ -72,7 +72,7 @@ from repro.dse_campaign import store
 from repro.dse_campaign.config import AdaptiveConfig, CampaignConfig
 from repro.dse_campaign.fabric import (FaultInjection, LeaseBoard,
                                        _worker_main, campaign_config,
-                                       tile_span)
+                                       require_host_backend, tile_span)
 from repro.dse_campaign.frontier import hypervolume_2d, hypervolume_gain_2d
 from repro.dse_campaign.runner import (Campaign, CampaignResult,
                                        TileReduction, TileStat, WorkloadKey)
@@ -598,6 +598,7 @@ class _WorkerPool:
         fault = fault or FaultInjection()
         if fault.hang_worker is not None:
             raise ValueError("hang_worker is a LocalFabric-only injection")
+        require_host_backend("the adaptive worker pool")
         cfg = campaign_config(engine)
         self.n_tiles = engine.space.n_tiles()
         ctx = mp.get_context("spawn")  # jax is not fork-safe
@@ -607,8 +608,7 @@ class _WorkerPool:
         self.lost: set = set()
         self.duplicate_pending = fault.duplicate
         self.stats = {"deliveries": 0, "duplicates": 0, "reissued_tiles": 0,
-                      "lost_workers": [], "n_workers": int(n_workers),
-                      "worker_backends": {}}
+                      "lost_workers": [], "n_workers": int(n_workers)}
         for w in range(n_workers):
             worker_cfg = {}
             if fault.kill_worker == w:
@@ -630,7 +630,6 @@ class _WorkerPool:
             if kind == "ready":
                 ready.add(w)
                 self.idle.append(w)
-                self.stats["worker_backends"][w] = payload
             elif kind == "error":
                 raise RuntimeError(f"adaptive worker {w} failed: {payload}")
             self._reap()

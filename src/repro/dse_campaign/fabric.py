@@ -793,32 +793,34 @@ class LocalFabric:
 # multiprocess fabric (real workers, spawn)
 # ---------------------------------------------------------------------------
 
-def _pin_worker_to_cpu() -> str:
-    """Put this worker process on JAX's CPU backend; returns the backend.
+def require_host_backend(what: str) -> None:
+    """Refuse to spawn worker processes from a process whose JAX backend is
+    an accelerator.
 
     Workers are host processes.  An accelerator belongs to one process at a
-    time — the one that started the campaign — and a second process that
-    reaches for it fails or hangs.  A spawned child inherits the parent's
-    environment (on a TPU host, JAX picks the TPU by default), so the worker
-    pins the CPU before anything initializes a backend.  A worker whose
-    backend is already up on another platform refuses to start."""
+    time, and this one already holds it: a spawned child that inherits the
+    environment reaches for the same chip and fails or hangs.  Workers
+    pinned to the CPU instead would evaluate in another precision than the
+    coordinator's own evaluations (``retry_parked``), so one frontier would
+    mix the two.  Run the fabric with ``JAX_PLATFORMS=cpu``, or run the
+    campaign in one process (``Campaign.run``) on the chip."""
     import jax
-    jax.config.update("jax_platforms", "cpu")
     backend = jax.default_backend()
     if backend != "cpu":
-        raise RuntimeError(f"fabric worker started on the {backend!r} "
-                           "backend; workers must run on the CPU")
-    return backend
+        raise RuntimeError(
+            f"{what} spawns host worker processes, but this process runs JAX "
+            f"on the {backend!r} backend, which one process holds; run it "
+            "with JAX_PLATFORMS=cpu, or run the campaign in one process "
+            "(Campaign.run) on the chip")
 
 
 def _worker_main(worker_id: int, cfg: Dict, worker_cfg: Dict,
                  task_q, result_q) -> None:
     """Fabric worker loop (runs in a ``spawn`` child, on the CPU backend —
-    see ``_pin_worker_to_cpu``).
+    see ``require_host_backend``).
 
     Protocol (all messages are 5-tuples ``(kind, wid, tile, payload,
-    busy_s)``): emits ``("ready", wid, None, backend, 0.0)`` once warm,
-    then for each leased
+    busy_s)``): emits ``("ready", ...)`` once warm, then for each leased
     tile received on ``task_q`` evaluates it with the shared
     ``TileEvaluator`` and emits ``("result", wid, tile, TileReduction,
     busy_s)``; ``None`` on ``task_q`` is shutdown, answered with a terminal
@@ -833,7 +835,6 @@ def _worker_main(worker_id: int, cfg: Dict, worker_cfg: Dict,
     compile cost.
     """
     try:
-        backend = _pin_worker_to_cpu()
         evaluator = evaluator_from_config(cfg)
         tel = evaluator.telemetry
         c_busy = tel.counter("worker_busy_s_total")
@@ -843,7 +844,7 @@ def _worker_main(worker_id: int, cfg: Dict, worker_cfg: Dict,
             lo, hi = tile_span(space, 0)
             evaluator.reduce_tile(space.slice(lo, hi, with_candidates=False),
                                   lo)
-        result_q.put(("ready", worker_id, None, backend, 0.0))
+        result_q.put(("ready", worker_id, None, None, 0.0))
         die_on_nth = (worker_cfg or {}).get("die_on_nth_tile")
         die_on_tile = (worker_cfg or {}).get("die_on_tile")
         n_received = 0
@@ -907,6 +908,7 @@ class MultiprocessFabric:
                  max_respawns: int = 0, poison_threshold: int = 3):
         if n_workers < 1:
             raise ValueError("n_workers must be >= 1")
+        require_host_backend("MultiprocessFabric")
         self.campaign = campaign
         self.n_workers = int(n_workers)
         self.lease_timeout_s = float(lease_timeout_s)
@@ -948,7 +950,6 @@ class MultiprocessFabric:
         procs: Dict[int, mp.Process] = {}
         task_qs: Dict[int, object] = {}
         busy_s: Dict[int, float] = {}
-        backends: Dict[int, str] = {}
         worker_metrics: Dict[int, Dict] = {}
         idle: List[int] = []
         ready: set = set()
@@ -1020,7 +1021,6 @@ class MultiprocessFabric:
                     coord.register_worker(w)
                     idle.append(w)
                     ready.add(w)
-                    backends[w] = payload
                     if len(ready | lost) >= self.n_workers:
                         if window_t0 is None:
                             window_t0 = clock()
@@ -1120,7 +1120,6 @@ class MultiprocessFabric:
             "total_busy_s": sum(busy_final.values()),
             "window_s": window_s,
             "worker_metrics": worker_metrics,
-            "worker_backends": backends,
         }
         return coord.result(window_s)
 

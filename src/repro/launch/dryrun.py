@@ -43,8 +43,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, save: bool = True,
     (default ``art_dir()``)."""
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
-    mesh = make_production_mesh(multi_pod=multi_pod,
-                                devices=jax.devices("cpu"))
+    mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
     save_hlo = os.environ.get("REPRO_SAVE_HLO", "1") == "1"
     result = lower_cell(cfg, shape, mesh, overrides=overrides or {},

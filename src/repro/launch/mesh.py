@@ -16,17 +16,17 @@ import jax
 from jax.sharding import AxisType
 
 
-def make_production_mesh(*, multi_pod: bool = False, devices=None):
-    """The census mesh; ``devices`` defaults to ``jax.devices()``.  The
-    dry-run passes the host placeholder devices (``jax.devices("cpu")``) so
-    the census builds the same way on a machine that also holds a chip."""
+def make_production_mesh(*, multi_pod: bool = False):
+    """The census mesh, built from host placeholder devices
+    (``jax.devices("cpu")``): the production chips are simulated, so the
+    census builds the same way on a machine that also holds a chip."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape),
+                         devices=jax.devices("cpu"))
 
 
-def make_mesh(shape, axes, devices=None):
+def make_mesh(shape, axes):
     """Arbitrary mesh for tests / elastic restarts / DSE sweeps."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(AxisType.Auto,) * len(tuple(shape)),
-                         devices=devices)
+                         axis_types=(AxisType.Auto,) * len(tuple(shape)))

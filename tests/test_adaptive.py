@@ -295,15 +295,16 @@ def test_adaptive_distributed_matches_single_process(fault):
         assert stats["reissued_tiles"] >= 1
 
 
-def test_adaptive_workers_run_on_cpu_under_tpu_parent(monkeypatch):
-    """The adaptive worker pool spawns the fabric's workers: with the
-    parent's environment selecting the TPU they still start on the CPU
-    backend, and the result equals the single-process run."""
+def test_adaptive_worker_pool_refuses_tpu_parent(monkeypatch):
+    """A parent whose JAX backend is the TPU holds the chip; the adaptive
+    worker pool refuses to start instead of spawning workers that would
+    reach for it, and spawns no process.  The parent's backend is steered
+    here by replacing ``jax.default_backend``."""
+    import multiprocessing as mp
+    import jax
     cfg = campaign_cfg(adaptive_cfg(), n_workers=1)
-    single = AdaptiveCampaign([WL], cfg)
-    single.run()
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-    dr, stats = run_adaptive_distributed([WL], cfg)
-    assert stats["worker_backends"] == {0: "cpu"}
-    assert stats["lost_workers"] == []
-    assert frontiers_identical(dr.frontiers[KEY], single.frontiers[KEY])
+    before = set(mp.active_children())
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        run_adaptive_distributed([WL], cfg)
+    assert set(mp.active_children()) == before

@@ -326,20 +326,19 @@ def test_multiprocess_fabric_death_duplicate_identity(tmp_path,
         range(small_spec().n_tiles()))
 
 
-def test_multiprocess_workers_run_on_cpu_under_tpu_parent(
-        monkeypatch, single_process_result):
-    """On a TPU host the parent's environment selects the TPU, and spawned
-    workers inherit it.  Workers pin themselves to the CPU backend instead:
-    with the inherited environment saying ``tpu`` (there is none here, so a
-    worker that obeyed it would fail to start) the run still completes,
-    every worker reports the CPU, and the frontier is unchanged."""
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-    fab = MultiprocessFabric(campaign(), n_workers=2)
-    res = fab.run()
-    assert res.complete
-    assert fab.stats["worker_backends"] == {0: "cpu", 1: "cpu"}
-    assert fab.stats["lost_workers"] == []
-    assert_identical_frontiers(single_process_result.frontiers, res.frontiers)
+def test_multiprocess_fabric_refuses_tpu_parent(monkeypatch):
+    """On a TPU host the parent holds the chip, and spawned workers that
+    inherit its environment would reach for it.  The fabric refuses to start
+    there with an error that says how to run it, and spawns no process.
+    The parent's backend is steered here by replacing
+    ``jax.default_backend``."""
+    import multiprocessing as mp
+    import jax
+    before = set(mp.active_children())
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        MultiprocessFabric(campaign(), n_workers=2)
+    assert set(mp.active_children()) == before
 
 
 def test_multiprocess_fabric_rejects_hang_injection():
