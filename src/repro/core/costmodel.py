@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.hw import (CHIP_TABLE, ChipSpec, ChipTable, axis_link_counts,
                       get_chip, normalize_mesh)
+from repro.telemetry.trace import NULL_TRACER
 
 # default fraction of the collective payload attributed to model-parallel
 # collectives (activation all-gather/reduce-scatter on the model axis); the
@@ -524,10 +525,9 @@ def _screen_rows(energy, latency, feasible):
 def _compact_rows_host(keep, energy, latency, max_survivors: int):
     """numpy survivor compaction of screened [W, N] rows: (surv_idx, surv_e,
     surv_l) as [W, K] with ascending lanes, rows past the row's survivor
-    count zero-filled.  The host side of the reduction on backends where
-    device arrays are host memory anyway (CPU interpret); compiled
-    accelerator paths compact on device (``_compact_rows_device``) so only
-    O(K) crosses the link."""
+    count zero-filled.  Both fused evaluators compact here, on every
+    backend, from the full rows fetched to the host; ``_compact_rows_device``
+    is the same contract in jnp, which no evaluator calls yet."""
     w_count, n = keep.shape
     k = min(int(max_survivors), n)
     surv_idx = np.zeros((w_count, k), np.int64)
@@ -621,7 +621,8 @@ def sweep_workloads_reduced_jit(wl_cols, chip_cols: Dict, n_chips, freq_mhz,
                                 sim: SimConfig = SimConfig(),
                                 max_power_w=None, max_latency_s=None,
                                 min_hbm_fit: bool = True,
-                                max_survivors: int = 2048) -> SweepReduced:
+                                max_survivors: int = 2048,
+                                tracer=NULL_TRACER) -> SweepReduced:
     """The jit reference path of the fused on-device campaign evaluator.
 
     One launch evaluates ALL ``W`` workloads on one (padded) candidate tile —
@@ -633,28 +634,48 @@ def sweep_workloads_reduced_jit(wl_cols, chip_cols: Dict, n_chips, freq_mhz,
     precision tier); the Pallas kernel path (``repro.kernels.dse_sweep``)
     shares every helper and runs float64 in interpret mode.  ``chip_cols``
     needs the ``SWEEP_GATHER_FIELDS`` columns; ``wl_cols`` is the packed
-    [W, 6] ``WL_COLS`` matrix.
+    [W, 6] ``WL_COLS`` matrix; the inputs cross to the device inside the
+    jitted call.  ``tracer`` (a ``SpanTracer`` or ``Telemetry``) times the
+    host stages (``run_reduced_launch``).
     """
     w_count, n_wl_cols = np.shape(wl_cols)
     if n_wl_cols != len(WL_COLS):
         raise ValueError(f"wl_cols must be [W, {len(WL_COLS)}] ({WL_COLS})")
     cols = {k: chip_cols[k] for k in SWEEP_GATHER_FIELDS}
-    out = _jit_sweep_reduced(
-        sim, max_power_w, max_latency_s, bool(min_hbm_fit))(
-            np.asarray(wl_cols, np.float64), cols, n_chips, freq_mhz,
-            mesh_pod, mesh_data, mesh_model, valid)
-    return build_sweep_reduced(out, int(max_survivors))
+    fn = _jit_sweep_reduced(sim, max_power_w, max_latency_s,
+                            bool(min_hbm_fit))
+    return run_reduced_launch(
+        fn, (np.asarray(wl_cols, np.float64), cols, n_chips, freq_mhz,
+             mesh_pod, mesh_data, mesh_model, valid),
+        int(max_survivors), tracer)
+
+
+def run_reduced_launch(fn, args, max_survivors: int,
+                       tracer=NULL_TRACER) -> SweepReduced:
+    """The host stages of one fused launch, shared by both fused evaluators:
+    ``dispatch`` (``fn(*args)`` returns its futures), ``device_wait`` (the
+    host waits for the chip), ``fetch`` (every output to the host) and
+    ``host_compact`` (``build_sweep_reduced``), each a span of ``tracer``.
+    The wait is the one a fetch makes anyway: a tracing ``tracer`` takes it
+    first, so that it is timed apart from the copies; untraced, the first
+    copy waits."""
+    with tracer.span("dispatch"):
+        out = fn(*args)
+    if tracer.tracing:
+        import jax
+        with tracer.span("device_wait"):
+            jax.block_until_ready(out)
+    with tracer.span("fetch"):
+        out = tuple(np.asarray(o) for o in out)
+    with tracer.span("host_compact"):
+        return build_sweep_reduced(out, max_survivors)
 
 
 def build_sweep_reduced(out, max_survivors: int) -> SweepReduced:
     """Assemble the host-side ``SweepReduced`` from a fused launch's output
-    tuple (keep, n_surv, n_feas, ref_e, ref_l, e_full, l_full, feas_full).
-
-    Compaction runs in numpy: on CPU (this container, and interpret-mode
-    CI) device arrays ARE host memory, so the mask + gathers here cost a
-    memcpy — far less than an XLA prefix-scan compaction.  A compiled
-    accelerator deployment would swap in ``_compact_rows_device`` before
-    the transfer; the contract is identical.
+    tuple (keep, n_surv, n_feas, ref_e, ref_l, e_full, l_full, feas_full),
+    fetched to the host.  Survivors are compacted in numpy
+    (``_compact_rows_host``); the full rows stay for the overflow fallback.
     """
     keep = np.asarray(out[0])
     e_full, l_full = np.asarray(out[5]), np.asarray(out[6])

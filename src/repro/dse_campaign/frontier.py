@@ -26,6 +26,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import dse
+from repro.telemetry import coerce_telemetry
 
 
 def hypervolume_2d(energy_j, latency_s, ref_energy_j, ref_latency_s) -> float:
@@ -141,10 +142,15 @@ class StreamingFrontier:
     merge that contains feasible points (max energy/latency of that merge),
     so trajectory values are comparable across snapshots — and across a
     checkpoint/resume boundary, since the ref point rides in ``state_dict``.
+
+    ``telemetry`` is the owner's (a private ``NullTelemetry`` by default):
+    each merge's ``fold`` (union, dedup, Pareto mask, order) and
+    ``snapshot`` (trajectory point, hypervolume) are spans of it.
     """
 
     def __init__(self, ref_energy_j: Optional[float] = None,
-                 ref_latency_s: Optional[float] = None):
+                 ref_latency_s: Optional[float] = None, telemetry=None):
+        self.telemetry = coerce_telemetry(telemetry)
         self.candidates: List[dse.Candidate] = []
         self.energy_j = np.empty(0, np.float64)
         self.latency_s = np.empty(0, np.float64)
@@ -189,32 +195,37 @@ class StreamingFrontier:
         """Fold already-feasible, already-novel points into the skyline —
         the union / dedup-by-index / pareto core shared by ``merge`` and
         ``merge_reduced`` so the two entry points cannot diverge."""
-        # union: current frontier first so dedup-by-index keeps it
-        all_cands = self.candidates + new_cands
-        all_e = np.concatenate([self.energy_j, new_e])
-        all_l = np.concatenate([self.latency_s, new_l])
-        all_i = np.concatenate([self.indices, new_i])
-        _, first = np.unique(all_i, return_index=True)
-        first.sort()
-        all_e, all_l, all_i = all_e[first], all_l[first], all_i[first]
-        all_cands = [all_cands[i] for i in first]
-        mask = dse.pareto_mask(all_e, all_l, np.ones(len(all_i), bool))
-        sel = np.flatnonzero(mask)
-        # canonical order: latency, then energy, then global index —
-        # identical regardless of the merge order that produced the set
-        order = sel[np.lexsort((all_i[sel], all_e[sel], all_l[sel]))]
-        self.candidates = [all_cands[i] for i in order]
-        self.energy_j = all_e[order]
-        self.latency_s = all_l[order]
-        self.indices = all_i[order]
+        with self.telemetry.span("fold"):
+            # union: current frontier first so dedup-by-index keeps it
+            all_cands = self.candidates + new_cands
+            all_e = np.concatenate([self.energy_j, new_e])
+            all_l = np.concatenate([self.latency_s, new_l])
+            all_i = np.concatenate([self.indices, new_i])
+            _, first = np.unique(all_i, return_index=True)
+            first.sort()
+            all_e, all_l, all_i = all_e[first], all_l[first], all_i[first]
+            all_cands = [all_cands[i] for i in first]
+            mask = dse.pareto_mask(all_e, all_l, np.ones(len(all_i), bool))
+            sel = np.flatnonzero(mask)
+            # canonical order: latency, then energy, then global index —
+            # identical regardless of the merge order that produced the set
+            order = sel[np.lexsort((all_i[sel], all_e[sel], all_l[sel]))]
+            self.candidates = [all_cands[i] for i in order]
+            self.energy_j = all_e[order]
+            self.latency_s = all_l[order]
+            self.indices = all_i[order]
 
     def _snapshot(self, tile: int) -> None:
-        self.trajectory.append(FrontierSnapshot(
-            tile=tile, evaluated=self.evaluated, feasible=self.feasible_seen,
-            frontier_size=len(self),
-            best_energy_j=float(self.energy_j.min()) if len(self) else float("inf"),
-            best_latency_s=float(self.latency_s.min()) if len(self) else float("inf"),
-            hypervolume=self.hypervolume()))
+        with self.telemetry.span("snapshot"):
+            empty = not len(self)
+            self.trajectory.append(FrontierSnapshot(
+                tile=tile, evaluated=self.evaluated,
+                feasible=self.feasible_seen, frontier_size=len(self),
+                best_energy_j=(float("inf") if empty
+                               else float(self.energy_j.min())),
+                best_latency_s=(float("inf") if empty
+                                else float(self.latency_s.min())),
+                hypervolume=self.hypervolume()))
 
     def merge(self, candidates: Sequence[dse.Candidate], energy_j, latency_s,
               feasible=None, indices=None, tile: int = -1) -> int:
@@ -360,11 +371,11 @@ class StreamingFrontier:
         }
 
     @classmethod
-    def from_state(cls, state: Dict) -> "StreamingFrontier":
+    def from_state(cls, state: Dict, telemetry=None) -> "StreamingFrontier":
         """Rebuild a frontier from ``state_dict`` output; subsequent merges
         continue exactly as if the frontier had never been serialized."""
         fr = cls(ref_energy_j=state["ref_energy_j"],
-                 ref_latency_s=state["ref_latency_s"])
+                 ref_latency_s=state["ref_latency_s"], telemetry=telemetry)
         fr.candidates = [candidate_from_dict(d) for d in state["candidates"]]
         fr.energy_j = np.asarray(state["energy_j"], np.float64)
         fr.latency_s = np.asarray(state["latency_s"], np.float64)

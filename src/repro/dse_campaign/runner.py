@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import queue
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -58,8 +59,13 @@ from repro.dse_campaign.config import (EVALUATORS, CampaignConfig,
 from repro.dse_campaign.frontier import StreamingFrontier
 from repro.dse_campaign.space import SpaceSpec
 from repro.telemetry import coerce_telemetry
+from repro.telemetry.trace import NULL_TRACER
 
 WorkloadKey = Tuple[str, str]
+
+# per-process campaign sequence number: the request id on the root spans
+# of one ``Campaign.run`` (``tile_eval``, ``tile_wait``)
+_CAMPAIGN_SEQ = itertools.count(1)
 
 
 def workload_to_dict(wl: dse.Workload) -> Dict:
@@ -201,14 +207,17 @@ class _TilePrefetcher:
     tile(s) of a ``SpaceSpec.tiles`` generator while the main thread drives
     the device on the current one.  The worker does numpy-only work (no JAX
     dispatch), so it is safe alongside the evaluating thread; ``close()``
-    unblocks and retires it when iteration stops early (max_tiles)."""
+    unblocks and retires it when iteration stops early (max_tiles).  Each
+    step of the generator is a ``tile_slice`` root span of ``tracer`` on
+    the worker's thread."""
 
     _END = object()
 
-    def __init__(self, it, depth: int = 1):
+    def __init__(self, it, depth: int = 1, tracer=NULL_TRACER):
         self._q: queue.Queue = queue.Queue(maxsize=max(1, int(depth)))
         self._stop = threading.Event()
         self._err: Optional[BaseException] = None
+        self._tracer = tracer
         self._thread = threading.Thread(target=self._work, args=(it,),
                                         daemon=True)
         self._thread.start()
@@ -224,9 +233,11 @@ class _TilePrefetcher:
 
     def _work(self, it):
         try:
-            for item in it:
-                if not self._put(item):
-                    return
+            while True:
+                with self._tracer.span("tile_slice"):
+                    item = next(it, self._END)
+                if item is self._END or not self._put(item):
+                    break
         except BaseException as exc:  # re-raised on the consuming thread
             self._err = exc
         self._put(self._END)
@@ -276,6 +287,8 @@ class TileEvaluator:
     (``evaluator_fused_launches_total``); pass ``telemetry=`` to share a
     registry/tracer with the caller, or omit it for a private
     ``NullTelemetry`` (counters still count, tracing is free).
+    ``evaluator_overflows_total`` counts workloads whose screened survivors
+    overflowed ``max_survivors`` and were reduced on the host instead.
     """
 
     def __init__(self, workloads: Sequence[dse.Workload], config=None,
@@ -303,8 +316,8 @@ class TileEvaluator:
         self._c_fused = self.telemetry.counter("evaluator_fused_launches_total")
         self._c_candidates = self.telemetry.counter(
             "evaluator_candidates_total")
-        self._c_survivors = self.telemetry.counter(
-            "evaluator_survivors_total")
+        self._c_overflows = self.telemetry.counter(
+            "evaluator_overflows_total")
 
     @property
     def fused_launches(self) -> int:
@@ -390,21 +403,22 @@ class TileEvaluator:
                       ) -> costmodel.SweepReduced:
         """ONE fused launch: all workloads x one padded tile, skyline-reduced
         on device.  Spans wrap the host-side stages only — ``pad`` (array
-        staging) and ``launch`` (the device dispatch); tracing never enters
-        the jitted/Pallas code itself."""
+        staging) and ``launch``, whose children split it: ``pack``
+        (Pallas path), then ``dispatch``, ``device_wait``, ``fetch`` and
+        ``host_compact`` (both paths, ``costmodel.run_reduced_launch``);
+        tracing never enters the jitted/Pallas code itself."""
         self._c_fused.inc()
-        with self.telemetry.span("pad", n=len(batch)):
+        tel = self.telemetry
+        with tel.span("pad", n=len(batch)):
             arrays = self.padded_tile_arrays(batch)
         cons = self.constraint
-        with self.telemetry.span("launch", evaluator=self.evaluator,
-                                 n=len(batch)):
+        with tel.span("launch", evaluator=self.evaluator, n=len(batch)):
             if self.evaluator == "pallas":
                 from repro.kernels import ops
-                from repro.kernels.dse_sweep import pack_cand_cols
                 return ops.dse_sweep(
-                    pack_cand_cols(arrays), self.wl_cols, sim=self.sim,
-                    constraint=cons, max_survivors=self.max_survivors,
-                    n_valid=len(batch))
+                    arrays, self.wl_cols, sim=self.sim, constraint=cons,
+                    max_survivors=self.max_survivors, n_valid=len(batch),
+                    tracer=tel)
             return costmodel.sweep_workloads_reduced_jit(
                 self.wl_cols,
                 {k: arrays[k] for k in costmodel.SWEEP_GATHER_FIELDS},
@@ -413,7 +427,7 @@ class TileEvaluator:
                 sim=self.sim, max_power_w=cons.max_power_w,
                 max_latency_s=cons.max_latency_s,
                 min_hbm_fit=cons.min_hbm_fit,
-                max_survivors=self.max_survivors)
+                max_survivors=self.max_survivors, tracer=tel)
 
     # -- the normalized reduction -------------------------------------------
 
@@ -489,10 +503,12 @@ class TileEvaluator:
                         samp_l.append(np.asarray(
                             red.latency_full, np.float64)[wi][lidx])
                     if red.overflowed(wi):
-                        add(*self._reduce_rows(
-                            np.asarray(red.energy_full)[wi][:n],
-                            np.asarray(red.latency_full)[wi][:n],
-                            np.asarray(red.feasible_full)[wi][:n], lo))
+                        self._c_overflows.inc()
+                        with self.telemetry.span("overflow_reduce", wl=wi):
+                            add(*self._reduce_rows(
+                                np.asarray(red.energy_full)[wi][:n],
+                                np.asarray(red.latency_full)[wi][:n],
+                                np.asarray(red.feasible_full)[wi][:n], lo))
                         continue
                     k = int(red.n_survivors[wi])
                     nf = int(red.n_feasible[wi])
@@ -521,7 +537,6 @@ class TileEvaluator:
             sample_energy=tuple(samp_e) if lidx is not None else None,
             sample_latency=tuple(samp_l) if lidx is not None else None)
         self._c_candidates.inc(n * len(self.workloads))
-        self._c_survivors.inc(tr.n_survivors)
         return tr
 
 
@@ -560,7 +575,8 @@ class Campaign:
                                     telemetry=self.telemetry)
         self.checkpoint_every = int(cfg.checkpoint_every)
         self.frontiers: Dict[WorkloadKey, StreamingFrontier] = {
-            k: StreamingFrontier() for k in self.engine.workload_keys}
+            k: StreamingFrontier(telemetry=self.telemetry)
+            for k in self.engine.workload_keys}
         self.tile_stats: List[TileStat] = []
         self.next_tile = 0
 
@@ -713,7 +729,8 @@ class Campaign:
         camp.tile_stats = [TileStat(**s) for s in state["tile_stats"]]
         for key_str, fr_state in state["frontiers"].items():
             arch, shape = key_str.split("|", 1)
-            camp.frontiers[(arch, shape)] = StreamingFrontier.from_state(fr_state)
+            camp.frontiers[(arch, shape)] = StreamingFrontier.from_state(
+                fr_state, telemetry=camp.telemetry)
         return camp
 
     # -- folding ------------------------------------------------------------
@@ -725,10 +742,13 @@ class Campaign:
         Idempotent at tile granularity: re-folding an already-folded tile —
         a duplicate delivery on the fabric, or a replayed tile after a
         resume — changes neither the frontier nor its accounting."""
+        tel = self.telemetry
         for wi, wl in enumerate(self.workloads):
             gidx = tr.surv_gidx[wi]
+            with tel.span("materialize", wl=wi):
+                cands = self.space.candidates_at(gidx)
             self.frontiers[(wl.arch, wl.shape)].merge_reduced(
-                self.space.candidates_at(gidx), tr.surv_energy[wi],
+                cands, tr.surv_energy[wi],
                 tr.surv_latency[wi], gidx, span=(tr.lo, tr.hi),
                 n_feasible=tr.n_feasible[wi],
                 ref_energy_j=tr.ref_energy_j[wi],
@@ -742,7 +762,10 @@ class Campaign:
         campaign result.  ``max_tiles`` bounds THIS call (interruption point
         for resume demos/tests); with a ``checkpoint_path`` (defaulting to
         ``config.checkpoint_path``) the state is persisted every
-        ``checkpoint_every`` tiles and at the end."""
+        ``checkpoint_every`` tiles and at the end.  The root spans of the
+        call carry its campaign sequence number (``campaign``):
+        ``tile_eval`` per tile, and ``tile_wait`` for each wait on the
+        prefetcher for the next tile."""
         if checkpoint_path is None:
             checkpoint_path = self.config.checkpoint_path
         tel = self.telemetry
@@ -753,14 +776,21 @@ class Campaign:
         done_this_call = 0
         fused = self.fused
         engine = self.engine
+        seq = next(_CAMPAIGN_SEQ)
         tiles = _TilePrefetcher(self.space.tiles(
-            start_tile=self.next_tile, with_candidates=not fused))
+            start_tile=self.next_tile, with_candidates=not fused), tracer=tel)
         try:
-            for tile_no, lo, batch in tiles:
+            while True:
+                with tel.span("tile_wait", campaign=seq):
+                    item = next(tiles, None)
+                if item is None:
+                    break
+                tile_no, lo, batch = item
                 if max_tiles is not None and done_this_call >= max_tiles:
                     break
                 t0 = clock()
-                with tel.span("tile_eval", tile=tile_no, n=len(batch)):
+                with tel.span("tile_eval", tile=tile_no, n=len(batch),
+                              campaign=seq):
                     if fused:
                         tr = engine.reduce_tile(batch, lo)
                         with tel.span("merge", tile=tile_no):

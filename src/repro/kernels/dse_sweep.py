@@ -35,8 +35,9 @@ per tile — the same ``SweepReduced`` contract as the jit reference path
 
 from __future__ import annotations
 
+import contextlib
 import functools
-from typing import Optional
+from typing import Mapping, Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +45,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core import costmodel
+from repro.telemetry.trace import NULL_TRACER
 
 # packed candidate-column order of the [len(CAND_COLS), N] matrix the kernel
 # consumes: batch axes first, then the gathered chip-table columns
@@ -139,7 +141,7 @@ def _jit_dse_sweep(sim: costmodel.SimConfig, max_power_w, max_latency_s,
     return jax.jit(run)
 
 
-def pack_cand_cols(arrays: dict, dtype=np.float64) -> np.ndarray:
+def pack_cand_cols(arrays: Mapping, dtype=np.float64) -> np.ndarray:
     """Stack the ``CAND_COLS`` entries of ``arrays`` into the packed matrix."""
     return np.stack([np.asarray(arrays[k], dtype) for k in CAND_COLS])
 
@@ -168,34 +170,41 @@ def _pad_lanes(cand_cols: np.ndarray, n_valid: int,
     return cand_cols
 
 
-def dse_sweep_reduced(cand_cols: np.ndarray, wl_cols: np.ndarray, *,
+def dse_sweep_reduced(cand_arrays: Mapping, wl_cols: np.ndarray, *,
                       sim: costmodel.SimConfig = costmodel.SimConfig(),
                       max_power_w: Optional[float] = None,
                       max_latency_s: Optional[float] = None,
                       min_hbm_fit: bool = True,
                       max_survivors: int = 2048,
                       n_valid: Optional[int] = None,
-                      interpret: bool = True) -> costmodel.SweepReduced:
+                      interpret: bool = True,
+                      tracer=NULL_TRACER) -> costmodel.SweepReduced:
     """Fused sweep + on-device skyline reduction of one candidate tile.
 
-    ``cand_cols`` [len(CAND_COLS), N] / ``wl_cols`` [W, len(WL_COLS)] as
-    float64 numpy; ``n_valid`` marks the real (un-padded) tile length.
-    Returns the ``SweepReduced`` contract shared with the jit reference
-    path.  Interpret mode computes in float64 (scoped x64): the campaign
-    frontier it produces holds the numpy evaluator's exact candidate set,
-    with values agreeing to ~1 ulp (XLA fusion noise only).  Compiled mode
-    computes in float32.
+    ``cand_arrays`` maps every ``CAND_COLS`` name to a length-N column;
+    ``wl_cols`` is [W, len(WL_COLS)]; ``n_valid`` marks the real
+    (un-padded) tile length.  Returns the ``SweepReduced`` contract shared
+    with the jit reference path.  Interpret mode computes in float64
+    (scoped x64): the campaign frontier it produces holds the numpy
+    evaluator's exact candidate set, with values agreeing to ~1 ulp (XLA
+    fusion noise only).  Compiled mode computes in float32.
+
+    ``tracer`` (a ``SpanTracer`` or ``Telemetry``) times the host stages:
+    ``pack`` (column stack, lane padding, the float32 cast), then the
+    stages of ``costmodel.run_reduced_launch``; the packed inputs cross to
+    the device inside the jitted call, so their copy starts in
+    ``dispatch`` and ends within ``device_wait``.
     """
-    n = cand_cols.shape[1]
-    n_valid = n if n_valid is None else int(n_valid)
     wl_cols = np.asarray(wl_cols, np.float64)
-    cand_cols = _pad_lanes(np.asarray(cand_cols, np.float64), n_valid,
-                           wl_cols.shape[0])
+    with tracer.span("pack"):
+        cand_cols = pack_cand_cols(cand_arrays)
+        n_valid = cand_cols.shape[1] if n_valid is None else int(n_valid)
+        cand_cols = _pad_lanes(cand_cols, n_valid, wl_cols.shape[0])
+        if not interpret:
+            cand_cols = cand_cols.astype(np.float32)
+            wl_cols = wl_cols.astype(np.float32)
     fn = _jit_dse_sweep(sim, max_power_w, max_latency_s, bool(min_hbm_fit),
                         bool(interpret))
-    if interpret:
-        with jax.enable_x64(True):
-            out = fn(cand_cols, wl_cols)
-    else:
-        out = fn(cand_cols.astype(np.float32), wl_cols.astype(np.float32))
-    return costmodel.build_sweep_reduced(out, int(max_survivors))
+    with (jax.enable_x64(True) if interpret else contextlib.nullcontext()):
+        return costmodel.run_reduced_launch(fn, (cand_cols, wl_cols),
+                                            int(max_survivors), tracer)

@@ -18,10 +18,10 @@ import jax.numpy as jnp
 
 from repro.core import costmodel
 from repro.kernels.conv2d import conv2d_pallas
-from repro.kernels.dse_sweep import (CAND_COLS, dse_sweep_reduced,
-                                     pack_cand_cols)
+from repro.kernels.dse_sweep import dse_sweep_reduced
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
+from repro.telemetry.trace import NULL_TRACER
 
 
 def default_interpret() -> bool:
@@ -98,26 +98,30 @@ def conv2d(x, w, *, stride: int = 1, padding: str = "SAME", tile_h: int = 8,
                    interpret=_resolve_interpret(interpret))
 
 
-def dse_sweep(cand_cols, wl_cols, *,
+def dse_sweep(cand_arrays, wl_cols, *,
               sim: costmodel.SimConfig = costmodel.SimConfig(),
               constraint=None, max_survivors: int = 2048,
               n_valid: Optional[int] = None,
-              interpret: Optional[bool] = None) -> costmodel.SweepReduced:
+              interpret: Optional[bool] = None,
+              tracer=NULL_TRACER) -> costmodel.SweepReduced:
     """Fused on-device campaign evaluator (see ``kernels.dse_sweep``).
 
-    One launch evaluates all workload rows of ``wl_cols`` against the packed
-    candidate tile ``cand_cols`` and reduces each to its feasible Pareto
-    survivors + frontier-accounting aggregates.  ``constraint`` duck-types
+    One launch evaluates all workload rows of ``wl_cols`` against the
+    candidate tile ``cand_arrays`` (one column per ``CAND_COLS`` name,
+    packed here) and reduces each to its feasible Pareto survivors +
+    frontier-accounting aggregates.  ``constraint`` duck-types
     ``dse.Constraint`` (``max_power_w`` / ``max_latency_s`` /
     ``min_hbm_fit``); interpret mode (the CPU default) computes float64 —
     campaign frontiers then hold the numpy evaluator's exact candidate set
-    — and compiled mode computes float32.
+    — and compiled mode computes float32.  ``tracer`` times the host
+    stages (``dse_sweep_reduced``).
     """
     kw = dict(max_power_w=None, max_latency_s=None, min_hbm_fit=True)
     if constraint is not None:
         kw = dict(max_power_w=constraint.max_power_w,
                   max_latency_s=constraint.max_latency_s,
                   min_hbm_fit=constraint.min_hbm_fit)
-    return dse_sweep_reduced(cand_cols, wl_cols, sim=sim,
+    return dse_sweep_reduced(cand_arrays, wl_cols, sim=sim,
                              max_survivors=max_survivors, n_valid=n_valid,
-                             interpret=_resolve_interpret(interpret), **kw)
+                             interpret=_resolve_interpret(interpret),
+                             tracer=tracer, **kw)

@@ -327,9 +327,11 @@ class SelectionEngine:
     sequential ones.
 
     Observability: pass ``telemetry=`` to share a metrics registry / tracer
-    with the caller (per-path ``selection_latency_s`` histograms,
-    ``selection_queries_total`` counters, the ``selection_deadline_ema_s``
-    gauge and the mini-campaign spans land there); the default is a private
+    with the caller (per-path ``selection_latency_s`` histograms of each
+    query's time from ``submit`` to the end of the ``flush`` that answers
+    it, ``selection_queries_total`` counters, the
+    ``selection_deadline_ema_s`` gauge, a ``queue_wait`` span per query and
+    the answer paths' spans land there); the default is a private
     ``NullTelemetry`` — counters still count, tracing is free.  The EMA the
     deadline triage BRANCHES on stays a plain attribute; the gauge only
     mirrors it (instrumented values never feed computation).
@@ -430,6 +432,11 @@ class SelectionEngine:
         """
         queries, self.pending = self.pending, []
         tel = self.telemetry
+        if tel.tracing:
+            taken = self._clock()
+            for q in queries:
+                tel.tracer.record("queue_wait", q.submitted_s, taken,
+                                  qid=q.qid)
         answers: Dict[int, SelectionAnswer] = {}
         novel: List[SelectionQuery] = []
         for q in queries:
@@ -464,7 +471,8 @@ class SelectionEngine:
         for group in groups.values():
             t0 = self._clock()
             try:
-                with tel.span("mini_campaign", n_queries=len(group)):
+                with tel.span("mini_campaign",
+                              qids=[q.qid for q in group]):
                     fronts, gidx = self._mini_campaign(
                         [q.workload for q in group],
                         self._query_constraint(group[0]))
@@ -499,13 +507,14 @@ class SelectionEngine:
                 answers[q.qid] = self._answer_from_frontier(
                     q, front, "mini_campaign", dt / len(group),
                     verified_gidx=gidx)
+        done = self._clock()
         for q in queries:
             ans = answers[q.qid]
             self.stats["queries"] += 1
             self.stats[ans.provenance] += 1
             tel.counter("selection_queries_total", path=ans.provenance).inc()
             tel.histogram("selection_latency_s",
-                          path=ans.provenance).observe(ans.wall_s)
+                          path=ans.provenance).observe(done - q.submitted_s)
         return [answers[q.qid] for q in queries]
 
     # -- the three answer paths ---------------------------------------------
@@ -590,11 +599,13 @@ class SelectionEngine:
         for ranking a top slice, which is why the slice is always verified
         exactly before being served as ``mini_campaign``.
         """
-        cfg = get_config(wl.arch)
-        shape = SHAPES[wl.shape.split(":", 1)[0]]
-        energy, latency, feasible, _, _ = _dse.predict_space(
-            cfg, shape, self.config.power_model, self.config.cycles_model,
-            self._full_space_batch(), constraint)
+        with self.telemetry.span("predict"):
+            cfg = get_config(wl.arch)
+            shape = SHAPES[wl.shape.split(":", 1)[0]]
+            energy, latency, feasible, _, _ = _dse.predict_space(
+                cfg, shape, self.config.power_model,
+                self.config.cycles_model, self._full_space_batch(),
+                constraint)
         return energy, latency, feasible
 
     def _answer_predictor_only(self, q: SelectionQuery,
@@ -678,21 +689,24 @@ class SelectionEngine:
         tr = ev.reduce_tile(batch, 0)
         self._c_fused.inc(ev._c_fused.value - launches_before)
         fronts: List[_dse.ParetoFrontier] = []
-        for wi, wl in enumerate(workloads):
-            loc = tr.surv_gidx[wi]                 # local slice positions
-            fr = StreamingFrontier()
-            fr.merge_reduced(
-                self.space.candidates_at(gidx[loc]), tr.surv_energy[wi],
-                tr.surv_latency[wi], loc, span=(0, int(gidx.size)),
-                n_feasible=tr.n_feasible[wi],
-                ref_energy_j=tr.ref_energy_j[wi],
-                ref_latency_s=tr.ref_latency_s[wi], tile=0)
-            front = fr.as_pareto_frontier(wl)
-            fronts.append(_dse.ParetoFrontier(
-                workload=wl, candidates=front.candidates,
-                energy_j=front.energy_j, latency_s=front.latency_s,
-                indices=gidx[front.indices],
-                feasible_count=front.feasible_count))
+        tel = self.telemetry
+        with tel.span("merge"):
+            for wi, wl in enumerate(workloads):
+                loc = tr.surv_gidx[wi]                 # local slice positions
+                with tel.span("materialize", wl=wi):
+                    cands = self.space.candidates_at(gidx[loc])
+                fr = StreamingFrontier(telemetry=tel)
+                fr.merge_reduced(
+                    cands, tr.surv_energy[wi], tr.surv_latency[wi], loc,
+                    span=(0, int(gidx.size)), n_feasible=tr.n_feasible[wi],
+                    ref_energy_j=tr.ref_energy_j[wi],
+                    ref_latency_s=tr.ref_latency_s[wi], tile=0)
+                front = fr.as_pareto_frontier(wl)
+                fronts.append(_dse.ParetoFrontier(
+                    workload=wl, candidates=front.candidates,
+                    energy_j=front.energy_j, latency_s=front.latency_s,
+                    indices=gidx[front.indices],
+                    feasible_count=front.feasible_count))
         return fronts, gidx
 
 

@@ -13,12 +13,20 @@ windows, serving latencies) use instead of raw ``time.perf_counter()`` —
 inject ``repro.dse_campaign.fabric.FakeClock`` and every telemetry
 timestamp in the system becomes deterministic.
 
+A tracing ``Telemetry`` mirrors each span into the JAX profiler as a
+``repro.<name>`` annotation, and records JAX's lowering and compilation
+time spans as ``lower`` / ``compile`` roots (``fun_name`` attr): the first
+tracing ``Telemetry`` built registers one ``jax.monitoring`` listener,
+which records each compile into the tracing ``Telemetry`` (or several)
+with a span open on the compiling thread.
+
 ``NullTelemetry`` is the default everywhere and the disabled-path
 contract: **metrics still count** (they are O(1) scalar writes, and
 back-compat surfaces like ``TileEvaluator.fused_launches`` read them) but
 **tracing is free** — ``span()`` returns a process-wide no-op singleton,
-nothing is buffered, and the instrumented hot paths add <2% throughput
-overhead (gated in ``benchmarks/dse_campaign.py``).
+nothing is buffered, no JAX listener is registered, and the instrumented
+hot paths add <2% throughput overhead (gated in
+``benchmarks/dse_campaign.py``).
 
 The one rule that keeps observability safe: no instrumented value may feed
 computation.  Metrics and spans are readings; the frontier identity gates
@@ -40,7 +48,9 @@ See ``docs/observability.md`` for the span/metric glossary.
 
 from __future__ import annotations
 
+import threading
 import time
+import weakref
 from typing import Callable, Dict, Optional
 
 from repro.telemetry.metrics import (Counter, Gauge, Histogram,
@@ -55,6 +65,47 @@ __all__ = [
 ]
 
 
+# JAX's time-span events recorded as spans, by the span name they get
+JAX_SPANS = {"/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+             "/jax/core/compile/backend_compile_duration": "compile"}
+
+_live: "weakref.WeakSet[Telemetry]" = weakref.WeakSet()
+_listener_lock = threading.Lock()
+_listening = False
+
+
+def _on_jax_time_span(event: str, start_time: float, end_time: float,
+                      **kwargs) -> None:
+    """``jax.monitoring`` time-span listener: one of JAX's lowerings or
+    compilations as a root span of each live tracing ``Telemetry`` with a
+    span open on the compiling thread, so the request that paid for the
+    compile holds it.  JAX calls this as the compile ends: the span ends
+    now on the tracer's clock and lasts JAX's measured duration."""
+    name = JAX_SPANS.get(event)
+    if name is None:
+        return
+    with _listener_lock:
+        tels = list(_live)
+    for tel in tels:
+        tr = tel.tracer
+        if tr.open_depth():
+            t1 = tr.clock()
+            tr.record(name, t1 - (end_time - start_time), t1,
+                      fun_name=kwargs.get("fun_name"))
+
+
+def _watch_jax(tel: "Telemetry") -> None:
+    """Add ``tel`` to the listener's audience; register the listener once."""
+    global _listening
+    with _listener_lock:
+        _live.add(tel)
+        if not _listening:
+            import jax.monitoring
+            jax.monitoring.register_event_time_span_listener(
+                _on_jax_time_span)
+            _listening = True
+
+
 class Telemetry:
     """The injectable observability bundle: metrics + tracer + clock."""
 
@@ -67,6 +118,7 @@ class Telemetry:
         self.metrics = MetricsRegistry(clock=clock)
         self.tracer = SpanTracer(clock=clock, wall_clock=wall_clock,
                                  capacity=trace_capacity)
+        _watch_jax(self)
 
     # -- tracing -------------------------------------------------------------
 

@@ -7,6 +7,14 @@ keyword attributes.  Records land in a bounded ring buffer (a deque), so a
 week-long campaign traces its most recent window instead of growing without
 bound.
 
+Each span also opens a ``jax.profiler.TraceAnnotation("repro.<name>")``, so a
+profile of the running program shows its spans beside the device ops, on the
+profiler's own clock.  Without a profiler session an annotation costs about a
+microsecond.
+
+Spans known only after they end (a query's wait in the queue, a compile that
+JAX reports) go in through ``SpanTracer.record`` as roots.
+
 Two hard rules the instrumented call sites follow:
 
 * spans wrap HOST code only — a span may surround a ``pallas_call`` or
@@ -31,7 +39,7 @@ import json
 import os
 import threading
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List
 
 # process-wide span id sequence: ids stay unique when several tracers run
 # in one process (campaign + coordinator + tests), which the trace-report
@@ -71,7 +79,8 @@ class _Span:
     and two injected-clock reads — the <2% overhead gate in
     ``benchmarks/dse_campaign.py`` rides on this."""
 
-    __slots__ = ("tracer", "name", "attrs", "sid", "parent", "depth", "t0")
+    __slots__ = ("tracer", "name", "attrs", "sid", "parent", "depth", "t0",
+                 "ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, attrs: Dict):
         self.tracer = tracer
@@ -85,12 +94,18 @@ class _Span:
         self.parent = stack[-1].sid if stack else -1
         self.depth = len(stack)
         stack.append(self)
+        label = tracer._labels.get(self.name)
+        if label is None:
+            label = tracer._labels[self.name] = "repro." + self.name
+        self.ann = tracer._trace_annotation(label)
+        self.ann.__enter__()
         self.t0 = tracer.clock()            # last: exclude setup from dur
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         tracer = self.tracer
         t1 = tracer.clock()
+        self.ann.__exit__(None, None, None)
         stack = tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -107,8 +122,11 @@ class SpanTracer:
     the ring buffer is shared — deque appends are GIL-atomic, so no lock
     sits on the span exit path — and one export sees every thread's spans.
     ``capacity`` bounds retained spans: eviction drops the OLDEST records,
-    keeping the most recent window.
+    keeping the most recent window.  Every span is mirrored into the JAX
+    profiler as ``repro.<name>`` (so construction imports JAX).
     """
+
+    tracing = True
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter,
                  wall_clock: Callable[[], float] = time.time,
@@ -120,6 +138,9 @@ class SpanTracer:
         self.wall_epoch = wall_clock()      # wall anchor of the epoch
         self._buf = collections.deque(maxlen=self.capacity)
         self._local = threading.local()
+        from jax.profiler import TraceAnnotation
+        self._trace_annotation = TraceAnnotation
+        self._labels: Dict[str, str] = {}     # span name -> annotation label
 
     def _stack(self) -> List[_Span]:
         stack = getattr(self._local, "stack", None)
@@ -131,6 +152,17 @@ class SpanTracer:
         """A context manager timing one named span (attrs are free-form
         JSON-safe scalars: tile index, worker id, evaluator tier...)."""
         return _Span(self, name, attrs)
+
+    def record(self, name: str, t0: float, t1: float, **attrs) -> None:
+        """Record a root span known only after it ended (``t0``/``t1`` on
+        the tracer clock)."""
+        self._buf.append((name, next(_SPAN_IDS), -1, 0, threading.get_ident(),
+                          t0, t1, attrs))
+
+    def open_depth(self) -> int:
+        """How many of this tracer's spans are open on the calling
+        thread."""
+        return len(self._stack())
 
     @property
     def records(self) -> List[SpanRecord]:
@@ -207,11 +239,15 @@ class NullTracer:
     ``span()`` returns the process-wide ``NULL_SPAN`` singleton; the only
     per-call cost left is the caller's argument evaluation."""
 
+    tracing = False
     capacity = 0
     records: List[SpanRecord] = []
 
     def span(self, name: str = "", **attrs) -> _NullSpan:
         return NULL_SPAN
+
+    def record(self, name: str, t0: float, t1: float, **attrs) -> None:
+        pass
 
     def clear(self) -> None:
         pass

@@ -19,6 +19,8 @@ from repro.dse_campaign.frontier import StreamingFrontier
 from repro.serving.engine import PROVENANCES, SelectionEngine
 from repro.serving.frontier_index import (INDEX_SCHEMA_VERSION, FrontierIndex,
                                           family_key)
+from repro.dse_campaign.fabric import FakeClock
+from repro.telemetry import Telemetry
 
 BASE = {"flops": 3.2e14, "hbm_bytes": 4.5e13, "collective_bytes": 5e11,
         "wire_bytes": 7e11}
@@ -255,6 +257,56 @@ def test_batched_queries_one_launch_and_equal_to_sequential(offline):
         solo = sequential.select(w)
         assert frontiers_identical(got.frontier(), solo.frontier())
     assert sequential.fused_launches == 3  # one launch per lone query
+
+
+def test_novel_query_spans_nest_under_its_request(offline):
+    """A novel query's exact path records ``predict`` (with predictors),
+    the launch, and a ``merge`` with its own stages under the request's
+    ``mini_campaign``; its wait in the queue is a root ``queue_wait``; and
+    the first call on a new jitted sweep records JAX's ``lower``."""
+    _, _, index = offline
+    cfg = SelectionEngine._config_from_index(index).replace(
+        power_model=StubModel(40.0), cycles_model=StubModel(1e9))
+    tel = Telemetry()
+    engine = SelectionEngine(index, cfg, verify_top=16, telemetry=tel)
+    # a constraint no other test sweeps under: its sweep compiles here
+    answer = engine.select(NOVEL, constraint=dse.Constraint(
+        max_power_w=51_234.0))
+    assert answer.provenance == "mini_campaign"
+    records = tel.tracer.records
+    by_sid = {r.sid: r for r in records}
+    (mini,) = [r for r in records if r.name == "mini_campaign"]
+    assert mini.attrs["qids"] == [answer.qid]
+    under = {r.name for r in records if r.parent == mini.sid}
+    assert {"predict", "pad", "launch", "compact", "merge"} <= under
+    (merge,) = [r for r in records if r.name == "merge"]
+    assert {r.name for r in records if r.parent == merge.sid} == {
+        "materialize", "fold", "snapshot"}
+    (wait,) = [r for r in records if r.name == "queue_wait"]
+    assert wait.parent == -1 and wait.attrs == {"qid": answer.qid}
+    assert wait.t1 <= mini.t0
+    lowered = [r for r in records if r.name == "lower"]
+    assert lowered and all(r.parent not in by_sid for r in lowered)
+
+
+def test_selection_latency_is_submit_to_answer():
+    """``selection_latency_s`` observes each query's time from ``submit``
+    to the ``flush`` that answers it, not a share of the flush."""
+    camp = Campaign(CACHED, serving_config())
+    camp.run()
+    clock = FakeClock(100.0)
+    tel = Telemetry(clock=clock)
+    engine = SelectionEngine(FrontierIndex.from_campaign(camp),
+                             telemetry=tel)
+    engine.submit(CACHED[0])
+    clock.advance(2.0)
+    engine.submit(CACHED[1])
+    clock.advance(1.0)
+    engine.flush()
+    hist = tel.histogram("selection_latency_s", path="index_exact")
+    assert hist.samples == [3.0, 1.0]
+    waits = [r for r in tel.tracer.records if r.name == "queue_wait"]
+    assert [r.dur for r in waits] == [3.0, 1.0]
 
 
 # --- the one-CampaignConfig API contract --------------------------------------

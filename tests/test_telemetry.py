@@ -4,20 +4,26 @@ input.  The headline identity: a fully instrumented campaign's frontier is
 BITWISE-equal to an uninstrumented one (``NullTelemetry`` default), so the
 registry/tracer can ride every hot path without touching results."""
 
+import glob
 import json
+import os
+import subprocess
+import sys
 import threading
 
 import numpy as np
 import pytest
 
 from repro.core import dse
-from repro.dse_campaign import (Campaign, FakeClock, LocalFabric,
-                                MultiprocessFabric, SliceVariant, SpaceSpec,
-                                frontiers_identical)
+from repro.dse_campaign import (Campaign, CampaignConfig, FakeClock,
+                                LocalFabric, MultiprocessFabric, SliceVariant,
+                                SpaceSpec, frontiers_identical)
 from repro.telemetry import (MetricsRegistry, NullTelemetry, SpanTracer,
                              Telemetry, coerce_telemetry, metric_value)
 from repro.telemetry.trace import NULL_SPAN
 from tools import trace_report
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 BASE = {"flops": 3.2e14, "hbm_bytes": 4.5e13, "collective_bytes": 5e11,
         "wire_bytes": 7e11}
@@ -295,3 +301,220 @@ class TestInstrumentationIsAReading:
             assert busy is not None and busy >= 0.0
             # stats' busy ledger uses the worker-shipped totals
             assert fabric.stats["worker_busy_s"][w] == busy
+
+
+# ------------------------------------------ the stages between two launches --
+
+# span -> the parent span it nests under (None: a root)
+LAUNCH_STAGES = {"dispatch": "launch", "device_wait": "launch",
+                 "fetch": "launch", "host_compact": "launch"}
+PALLAS_STAGES = {"pack": "launch"}
+CAMPAIGN_STAGES = {"overflow_reduce": "compact", "materialize": "merge",
+                   "fold": "merge", "snapshot": "merge", "tile_wait": None,
+                   "tile_slice": None, "lower": None, "compile": None}
+# a constraint of its own per evaluator, so that its sweep compiles afresh
+# under the test's tracing telemetry and JAX reports lowering + compiling
+FRESH_POWER_W = {"pallas": 50_101.0, "jit": 50_102.0}
+
+
+def stage_config(evaluator, max_survivors=4):
+    return CampaignConfig(
+        space=small_spec(), evaluator=evaluator,
+        constraint=dse.Constraint(max_power_w=FRESH_POWER_W[evaluator]),
+        max_survivors=max_survivors)
+
+
+def parents(records):
+    by_sid = {r.sid: r for r in records}
+    return {(r.name, by_sid[r.parent].name if r.parent in by_sid else None)
+            for r in records}
+
+
+class TestLaunchStageSpans:
+    @pytest.mark.parametrize("evaluator", ["pallas", "jit"])
+    def test_every_stage_span_under_its_parent(self, evaluator, tmp_path):
+        tel = Telemetry()
+        traced = Campaign(WLS, stage_config(evaluator), telemetry=tel).run()
+        records = tel.tracer.records
+        expected = {**LAUNCH_STAGES, **CAMPAIGN_STAGES}
+        if evaluator == "pallas":
+            expected.update(PALLAS_STAGES)
+        got = parents(records)
+        for name, parent in expected.items():
+            assert (name, parent) in got, (name, parent, sorted(got))
+        if evaluator == "jit":     # it packs nothing: jit takes the columns
+            assert not {n for n, _ in got} & set(PALLAS_STAGES)
+        # the request id: every root span of the run carries the same one
+        seqs = {r.attrs["campaign"] for r in records
+                if r.name in ("tile_eval", "tile_wait")}
+        assert len(seqs) == 1
+        n_tiles = traced.tiles_done
+        counts = {n: sum(r.name == n for r in records) for n in expected}
+        assert counts["dispatch"] == counts["fetch"] == n_tiles
+        assert counts["materialize"] == counts["fold"] == n_tiles * len(WLS)
+        # every overflowed workload has its span and its counter increment
+        overflows = tel.counter("evaluator_overflows_total").value
+        assert counts["overflow_reduce"] == overflows > 0
+        assert {r.attrs["fun_name"] for r in records if r.name == "lower"}
+        slices = [r for r in records if r.name == "tile_slice"]
+        main = {r.thread_id for r in records if r.name == "tile_eval"}
+        assert {r.thread_id for r in slices}.isdisjoint(main)
+        events = trace_report.load_events(
+            tel.export_trace(str(tmp_path / "trace.json")))
+        assert trace_report.check(events, list(expected)) == []
+
+    @pytest.mark.parametrize("evaluator", ["pallas", "jit"])
+    def test_frontiers_bitwise_equal_with_telemetry_on_off_and_null(
+            self, evaluator):
+        cfg = stage_config(evaluator, max_survivors=8)
+        runs = [Campaign(WLS, cfg, telemetry=t).run()
+                for t in (Telemetry(), None, NullTelemetry())]
+        for other in runs[1:]:
+            for key in runs[0].frontiers:
+                assert frontiers_identical(runs[0].frontiers[key],
+                                           other.frontiers[key])
+
+    def test_launch_children_are_its_stages_and_untraced_adds_nothing(
+            self, monkeypatch):
+        """Traced, ``launch`` holds exactly its five stages (Pallas path);
+        untraced, the launch makes no explicit copy and no separate wait:
+        the inputs cross inside the jitted call, the first fetch waits."""
+        import jax
+        tel = Telemetry()
+        Campaign(WLS, stage_config("pallas"), telemetry=tel).run()
+        records = tel.tracer.records
+        launches = {r.sid for r in records if r.name == "launch"}
+        assert {r.name for r in records if r.parent in launches} == {
+            "pack", "dispatch", "device_wait", "fetch", "host_compact"}
+
+        def refuse(name):
+            def call(*args, **kwargs):
+                raise AssertionError(f"an untraced launch called jax.{name}")
+            return call
+        for name in ("device_put", "block_until_ready"):
+            monkeypatch.setattr(jax, name, refuse(name))
+        assert Campaign(WLS, stage_config("pallas")).run().complete
+
+    def test_null_telemetry_records_nothing_and_registers_no_listener(self):
+        """In a fresh process: a campaign under ``NullTelemetry`` leaves
+        no span and no ``jax.monitoring`` listener; the first tracing
+        ``Telemetry`` registers exactly one."""
+        code = """
+from jax._src import monitoring
+import repro.telemetry as telemetry
+from repro.core import dse
+from repro.dse_campaign import Campaign, CampaignConfig, SpaceSpec
+wl = dse.Workload("qwen3_14b", "train_4k", {"flops": 3.2e14,
+    "hbm_bytes": 4.5e13, "collective_bytes": 5e11, "wire_bytes": 7e11},
+    256, 0.5)
+listeners = lambda: [f for f in monitoring.get_event_time_span_listeners()
+                     if f is telemetry._on_jax_time_span]
+null = telemetry.NullTelemetry()
+cfg = CampaignConfig(space=SpaceSpec(chips=("tpu-v5e",), chip_counts=(16,),
+                     freq_points=3, chunk_size=16), evaluator="pallas")
+Campaign([wl], cfg, telemetry=null).run()
+assert null.tracer.records == [] and not telemetry._listening
+assert listeners() == []
+telemetry.Telemetry(); telemetry.Telemetry()
+assert telemetry._listening and len(listeners()) == 1
+print("ok")
+"""
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.pathsep.join(
+                       [os.path.join(REPO, "src"),
+                        os.environ.get("PYTHONPATH", "")]))
+        p = subprocess.run([sys.executable, "-c", code], env=env,
+                           capture_output=True, text=True, timeout=300)
+        assert p.returncode == 0 and p.stdout.strip() == "ok", p.stderr
+
+    def test_dead_telemetry_leaves_the_listener_audience(self):
+        import gc
+        import repro.telemetry as telemetry
+        tel = Telemetry()
+        assert tel in telemetry._live
+        n = len(telemetry._live)
+        del tel
+        gc.collect()
+        assert len(telemetry._live) == n - 1
+
+    def test_record_puts_a_finished_span_in_as_a_root(self):
+        tr = SpanTracer(clock=FakeClock(10.0), wall_clock=FakeClock(1000.0))
+        with tr.span("outer"):
+            tr.record("queue_wait", 9.5, 10.0, qid=3)
+        rec = {r.name: r for r in tr.records}["queue_wait"]
+        assert rec.parent == -1 and rec.depth == 0 and rec.dur == 0.5
+        assert rec.attrs == {"qid": 3}
+
+    def test_a_compile_is_recorded_only_where_a_span_is_open(self):
+        """JAX's lowering and compiling land in the tracing ``Telemetry``
+        whose span is open on the compiling thread, and in no other."""
+        import jax
+        import jax.numpy as jnp
+        busy, idle = Telemetry(), Telemetry()
+        x = jnp.arange(11.0)
+        with busy.span("request"):
+            jax.jit(lambda v: v * 3.0 + 1.375)(x).block_until_ready()
+        recs = {r.name: r for r in busy.tracer.records}
+        assert {"request", "lower", "compile"} <= set(recs)
+        req = recs["request"]
+        for name in ("lower", "compile"):
+            r = recs[name]
+            assert r.parent == -1 and r.attrs["fun_name"]
+            assert 0 <= r.dur and req.t0 <= r.t1 <= req.t1
+        assert idle.tracer.records == []
+
+    def test_a_compile_on_another_thread_is_not_recorded(self):
+        """A span open on this thread does not claim a compile that another
+        thread runs."""
+        import threading
+
+        import jax
+        import jax.numpy as jnp
+        tel = Telemetry()
+        x = jnp.arange(13.0)
+        with tel.span("request"):
+            t = threading.Thread(target=lambda: jax.jit(
+                lambda v: v * 5.0 - 0.625)(x).block_until_ready())
+            t.start()
+            t.join()
+        assert {r.name for r in tel.tracer.records} == {"request"}
+
+    def test_span_shows_in_a_cpu_profile_as_repro_name(self, tmp_path):
+        import jax
+        tel = Telemetry()
+        with jax.profiler.trace(str(tmp_path)):
+            with tel.span("launch"):
+                with tel.span("device_wait"):
+                    pass
+        path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                         recursive=True)[0]
+        names = {e.name for plane in
+                 jax.profiler.ProfileData.from_file(path).planes
+                 for line in plane.lines for e in line.events}
+        assert {"repro.launch", "repro.device_wait"} <= names
+
+
+def test_trace_report_self_time_subtracts_children_coverage():
+    tel = Telemetry(clock=FakeClock(0.0))
+    clock = tel.clock
+    with tel.span("tile_eval"):
+        clock.advance(1.0)
+        with tel.span("launch"):
+            with tel.span("pack"):
+                clock.advance(2.0)
+            clock.advance(0.5)
+            with tel.span("fetch"):
+                clock.advance(1.5)
+        with tel.span("merge"):
+            clock.advance(3.0)
+    events = tel.chrome_trace()["traceEvents"]
+    events = [e for e in events if e["ph"] == "X"]
+    agg = trace_report.summarize(events)
+    assert agg["tile_eval"]["self_us"] == pytest.approx(1.0e6)
+    assert agg["launch"]["self_us"] == pytest.approx(0.5e6)
+    assert agg["pack"]["self_us"] == pytest.approx(2.0e6)
+    tree = trace_report.stage_tree(events)
+    assert tree[("tile_eval", "launch", "fetch")]["total_us"] == \
+        pytest.approx(1.5e6)
+    assert sum(row["self_us"] for p, row in tree.items()
+               if p[0] == "tile_eval") == pytest.approx(8.0e6)

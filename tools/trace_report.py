@@ -4,11 +4,14 @@ Reads the ``trace_event`` JSON written by ``Telemetry.export_trace`` /
 ``SpanTracer.export`` (complete ``"ph": "X"`` events whose ``args`` carry
 the span id, parent id and nesting depth) and prints:
 
-* **top spans** — per-name count / total / mean / max duration, sorted by
-  total time;
-* **per-stage share** — each span name's share of the total ``tile_eval``
-  time (the campaign's unit of work), so "where does a tile's wall go?"
-  (pad vs. launch vs. compact vs. merge) is one glance;
+* **top spans** — per-name count / total / self / mean / max duration,
+  sorted by total time; a span's self time is its duration minus the part
+  of it that its child spans cover;
+* **per-stage share** — the span tree under each request span
+  (``tile_eval`` for a campaign tile, ``mini_campaign`` for a query's
+  exact path), each stage's total and self time as a share of the
+  request's total, so "where does a tile's wall go?" is one glance and the
+  self column adds up to 100% without counting a nested stage twice;
 * **worker utilization** — per-worker busy time from ``tile_eval`` spans
   that carry a ``worker`` attr (fabric traces), as a share of the trace's
   observed wall.
@@ -37,6 +40,8 @@ from typing import Dict, List, Optional
 SLACK_US = 0.5
 
 DEFAULT_REQUIRED = ("tile_eval", "checkpoint_write", "lease")
+# roots of the per-stage table: the spans of one request
+REQUEST_SPANS = ("tile_eval", "mini_campaign")
 
 
 def load_events(path: str) -> List[Dict]:
@@ -49,18 +54,71 @@ def load_events(path: str) -> List[Dict]:
     return [e for e in events if e.get("ph") == "X"]
 
 
-def summarize(events: List[Dict]) -> Dict[str, Dict]:
-    """Per-name aggregates over the events' ``dur`` (µs)."""
-    agg: Dict[str, Dict] = {}
+def _sid(e: Dict):
+    return e.get("args", {}).get("sid")
+
+
+def self_times(events: List[Dict]) -> List[float]:
+    """Each event's self time (µs): its ``dur`` minus the union of its
+    children's intervals, clipped to its own."""
+    children: Dict[object, List[Dict]] = defaultdict(list)
     for e in events:
+        parent = e.get("args", {}).get("parent", -1)
+        if parent != -1:
+            children[parent].append(e)
+    out = []
+    for e in events:
+        lo, hi = e["ts"], e["ts"] + e["dur"]
+        covered, end = 0.0, lo
+        for c in sorted(children.get(_sid(e), ()), key=lambda c: c["ts"]):
+            s, t = max(c["ts"], end), min(c["ts"] + c["dur"], hi)
+            if t > s:
+                covered += t - s
+                end = t
+        out.append(max(e["dur"] - covered, 0.0))
+    return out
+
+
+def summarize(events: List[Dict]) -> Dict[str, Dict]:
+    """Per-name aggregates over the events' ``dur`` and self time (µs)."""
+    agg: Dict[str, Dict] = {}
+    for e, self_us in zip(events, self_times(events)):
         row = agg.setdefault(e["name"], {"count": 0, "total_us": 0.0,
-                                         "max_us": 0.0})
+                                         "self_us": 0.0, "max_us": 0.0})
         row["count"] += 1
         row["total_us"] += e["dur"]
+        row["self_us"] += self_us
         row["max_us"] = max(row["max_us"], e["dur"])
     for row in agg.values():
         row["mean_us"] = row["total_us"] / row["count"]
     return agg
+
+
+def stage_tree(events: List[Dict]) -> Dict[tuple, Dict]:
+    """Total and self time (µs) and count per span path: the names from
+    the root span down (a span whose parent is not in the trace roots its
+    own path)."""
+    by_sid = {_sid(e): e for e in events if _sid(e) is not None}
+    paths: Dict[object, tuple] = {}
+
+    def path(e: Dict) -> tuple:
+        sid = _sid(e)
+        if sid in paths:
+            return paths[sid]
+        parent = by_sid.get(e.get("args", {}).get("parent", -1))
+        p = (path(parent) if parent is not None else ()) + (e["name"],)
+        if sid is not None:
+            paths[sid] = p
+        return p
+
+    tree: Dict[tuple, Dict] = {}
+    for e, self_us in zip(events, self_times(events)):
+        row = tree.setdefault(path(e), {"count": 0, "total_us": 0.0,
+                                        "self_us": 0.0})
+        row["count"] += 1
+        row["total_us"] += e["dur"]
+        row["self_us"] += self_us
+    return tree
 
 
 def print_report(events: List[Dict], top: int = 15) -> None:
@@ -70,19 +128,28 @@ def print_report(events: List[Dict], top: int = 15) -> None:
     agg = summarize(events)
 
     print(f"{len(events)} spans, {len(agg)} distinct names\n")
-    print(f"{'span':<20} {'count':>7} {'total_ms':>10} {'mean_us':>10} "
-          f"{'max_us':>10}")
+    print(f"{'span':<20} {'count':>7} {'total_ms':>10} {'self_ms':>10} "
+          f"{'mean_us':>10} {'max_us':>10}")
     for name, row in sorted(agg.items(), key=lambda kv: -kv[1]["total_us"])[:top]:
         print(f"{name:<20} {row['count']:>7} {row['total_us'] / 1e3:>10.3f} "
+              f"{row['self_us'] / 1e3:>10.3f} "
               f"{row['mean_us']:>10.1f} {row['max_us']:>10.1f}")
 
-    tile_total = agg.get("tile_eval", {}).get("total_us", 0.0)
-    if tile_total > 0:
-        print(f"\nper-stage share of tile_eval "
-              f"({tile_total / 1e3:.3f} ms total):")
-        for name in ("tile_slice", "pad", "launch", "compact", "merge"):
-            if name in agg:
-                print(f"  {name:<18} {agg[name]['total_us'] / tile_total:>7.1%}")
+    tree = stage_tree(events)
+    for root in REQUEST_SPANS:
+        total = tree.get((root,), {}).get("total_us", 0.0)
+        if total <= 0:
+            continue
+        print(f"\nper-stage share of {root} ({total / 1e3:.3f} ms total, "
+              f"{tree[(root,)]['count']} spans):")
+        print(f"  {'stage':<24} {'total':>7} {'self':>7}")
+        for p in sorted((p for p in tree if p[0] == root),
+                        key=lambda p: [(-tree[p[:i + 1]]["total_us"], p[i])
+                                       for i in range(len(p))]):
+            row = tree[p]
+            label = "  " * (len(p) - 1) + p[-1]
+            print(f"  {label:<24} {row['total_us'] / total:>7.1%} "
+                  f"{row['self_us'] / total:>7.1%}")
 
     by_worker: Dict[object, float] = defaultdict(float)
     for e in events:
