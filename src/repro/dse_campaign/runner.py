@@ -46,7 +46,7 @@ import functools
 import itertools
 import queue
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -399,26 +399,54 @@ class TileEvaluator:
                        for k in costmodel.SWEEP_GATHER_FIELDS})
         return arrays
 
-    def sweep_reduced(self, batch: dse.CandidateBatch
+    def launch_lanes(self, n: int) -> int:
+        """The lane count a fused launch of an ``n``-candidate batch runs
+        on: the batch padded to ``chunk_size``, then to the kernel's
+        blocks for this evaluator's workload count."""
+        from repro.kernels.dse_sweep import padded_lanes
+        return padded_lanes(max(self.space.chunk_size, n),
+                            len(self.workloads))
+
+    def stage_tile(self, batch: dse.CandidateBatch):
+        """``batch`` staged once for many Pallas launches of this
+        evaluator's workload count (``ops.stage_dse_sweep``): the matrix
+        each ``sweep_reduced(batch)`` packs, on the device when
+        compiled.  Pass it back through ``sweep_reduced``'s ``stage``."""
+        from repro.kernels import ops
+        return ops.stage_dse_sweep(self.padded_tile_arrays(batch),
+                                   len(self.workloads), n_valid=len(batch))
+
+    def sweep_reduced(self, batch: dse.CandidateBatch,
+                      stage: Optional[Callable[[], Any]] = None
                       ) -> costmodel.SweepReduced:
         """ONE fused launch: all workloads x one padded tile, skyline-reduced
         on device.  Spans wrap the host-side stages only — ``pad`` (array
         staging) and ``launch``, whose children split it: ``pack``
         (Pallas path), then ``dispatch``, ``device_wait``, ``fetch`` and
         ``host_compact`` (both paths, ``costmodel.run_reduced_launch``);
-        tracing never enters the jitted/Pallas code itself."""
+        tracing never enters the jitted/Pallas code itself.
+
+        ``stage`` (Pallas only) returns ``batch`` as ``stage_tile`` staged
+        it; it is called inside ``pack``, and ``pad`` and the packing are
+        skipped."""
+        if stage is not None and self.evaluator != "pallas":
+            raise ValueError(f"a staged tile needs the pallas evaluator, "
+                             f"not {self.evaluator!r}")
         self._c_fused.inc()
         tel = self.telemetry
-        with tel.span("pad", n=len(batch)):
-            arrays = self.padded_tile_arrays(batch)
         cons = self.constraint
+        if stage is None:
+            with tel.span("pad", n=len(batch)):
+                arrays = self.padded_tile_arrays(batch)
         with tel.span("launch", evaluator=self.evaluator, n=len(batch)):
             if self.evaluator == "pallas":
                 from repro.kernels import ops
-                return ops.dse_sweep(
-                    arrays, self.wl_cols, sim=self.sim, constraint=cons,
-                    max_survivors=self.max_survivors, n_valid=len(batch),
-                    tracer=tel)
+                kw = dict(sim=self.sim, constraint=cons,
+                          max_survivors=self.max_survivors, tracer=tel)
+                if stage is not None:
+                    return ops.dse_sweep_staged(stage, self.wl_cols, **kw)
+                return ops.dse_sweep(arrays, self.wl_cols,
+                                     n_valid=len(batch), **kw)
             return costmodel.sweep_workloads_reduced_jit(
                 self.wl_cols,
                 {k: arrays[k] for k in costmodel.SWEEP_GATHER_FIELDS},
@@ -460,7 +488,8 @@ class TileEvaluator:
         return (lo + loc.astype(np.int64), e[loc], l[loc], n_feas,
                 ref_e, ref_l)
 
-    def reduce_tile(self, batch: dse.CandidateBatch, lo: int
+    def reduce_tile(self, batch: dse.CandidateBatch, lo: int,
+                    stage: Optional[Callable[[], Any]] = None
                     ) -> TileReduction:
         """Evaluate one tile for ALL workloads and reduce it to a
         ``TileReduction`` — the single entry point both the in-process fused
@@ -478,6 +507,9 @@ class TileEvaluator:
         fused path reads it off the already-materialized full rows, the
         per-workload path off each workload's evaluation — zero extra
         launches either way.
+
+        ``stage`` hands ``sweep_reduced`` the tile as ``stage_tile``
+        staged it (Pallas only).
         """
         n = len(batch)
         cols = {"gidx": [], "e": [], "l": [], "nf": [], "re": [], "rl": []}
@@ -494,7 +526,7 @@ class TileEvaluator:
             cols["rl"].append(rl)
 
         if self.fused:
-            red = self.sweep_reduced(batch)
+            red = self.sweep_reduced(batch, stage)
             with self.telemetry.span("compact", n=n):
                 for wi in range(len(self.workloads)):
                     if lidx is not None:

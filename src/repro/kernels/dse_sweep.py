@@ -37,7 +37,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Mapping, Optional
+from typing import Any, Callable, Mapping, Optional
 
 import jax
 import jax.numpy as jnp
@@ -170,6 +170,50 @@ def _pad_lanes(cand_cols: np.ndarray, n_valid: int,
     return cand_cols
 
 
+def stage_cand_cols(cand_arrays: Mapping, n_workloads: int, *,
+                    n_valid: Optional[int] = None,
+                    interpret: bool = True) -> np.ndarray:
+    """The launch-ready candidate matrix of one tile: the ``CAND_COLS``
+    columns of ``cand_arrays`` stacked (``pack_cand_cols``), padded to
+    ``padded_lanes(N, n_workloads)`` with ``valid`` 0 past ``n_valid``
+    (``_pad_lanes``), and float32 when compiled (float64 in interpret
+    mode).  It depends on the workloads only through their count, so one
+    staged matrix serves every launch of that tile with as many rows."""
+    cand_cols = pack_cand_cols(cand_arrays)
+    n_valid = cand_cols.shape[1] if n_valid is None else int(n_valid)
+    cand_cols = _pad_lanes(cand_cols, n_valid, n_workloads)
+    return cand_cols if interpret else cand_cols.astype(np.float32)
+
+
+def dse_sweep_staged(stage: Callable[[], Any], wl_cols: np.ndarray, *,
+                     sim: costmodel.SimConfig = costmodel.SimConfig(),
+                     max_power_w: Optional[float] = None,
+                     max_latency_s: Optional[float] = None,
+                     min_hbm_fit: bool = True,
+                     max_survivors: int = 2048,
+                     interpret: bool = True,
+                     tracer=NULL_TRACER) -> costmodel.SweepReduced:
+    """The fused launch on a staged candidate matrix.
+
+    ``stage()`` returns the ``stage_cand_cols`` matrix of the tile for
+    ``wl_cols``' row count: made on the spot, or made before and kept (on
+    the device when compiled).  It runs inside the ``pack`` span, with the
+    cast of ``wl_cols``; the stages of ``costmodel.run_reduced_launch``
+    follow.  A host matrix crosses to the device inside the jitted call,
+    so its copy starts in ``dispatch`` and ends within ``device_wait``.
+    """
+    wl_cols = np.asarray(wl_cols, np.float64)
+    with tracer.span("pack"):
+        cand_cols = stage()
+        if not interpret:
+            wl_cols = wl_cols.astype(np.float32)
+    fn = _jit_dse_sweep(sim, max_power_w, max_latency_s, bool(min_hbm_fit),
+                        bool(interpret))
+    with (jax.enable_x64(True) if interpret else contextlib.nullcontext()):
+        return costmodel.run_reduced_launch(fn, (cand_cols, wl_cols),
+                                            int(max_survivors), tracer)
+
+
 def dse_sweep_reduced(cand_arrays: Mapping, wl_cols: np.ndarray, *,
                       sim: costmodel.SimConfig = costmodel.SimConfig(),
                       max_power_w: Optional[float] = None,
@@ -189,22 +233,14 @@ def dse_sweep_reduced(cand_arrays: Mapping, wl_cols: np.ndarray, *,
     evaluator's exact candidate set, with values agreeing to ~1 ulp (XLA
     fusion noise only).  Compiled mode computes in float32.
 
-    ``tracer`` (a ``SpanTracer`` or ``Telemetry``) times the host stages:
-    ``pack`` (column stack, lane padding, the float32 cast), then the
-    stages of ``costmodel.run_reduced_launch``; the packed inputs cross to
-    the device inside the jitted call, so their copy starts in
-    ``dispatch`` and ends within ``device_wait``.
+    The tile is staged (``stage_cand_cols``) inside the launch's ``pack``
+    span and launched by ``dse_sweep_staged``; ``tracer`` (a
+    ``SpanTracer`` or ``Telemetry``) times the host stages.
     """
-    wl_cols = np.asarray(wl_cols, np.float64)
-    with tracer.span("pack"):
-        cand_cols = pack_cand_cols(cand_arrays)
-        n_valid = cand_cols.shape[1] if n_valid is None else int(n_valid)
-        cand_cols = _pad_lanes(cand_cols, n_valid, wl_cols.shape[0])
-        if not interpret:
-            cand_cols = cand_cols.astype(np.float32)
-            wl_cols = wl_cols.astype(np.float32)
-    fn = _jit_dse_sweep(sim, max_power_w, max_latency_s, bool(min_hbm_fit),
-                        bool(interpret))
-    with (jax.enable_x64(True) if interpret else contextlib.nullcontext()):
-        return costmodel.run_reduced_launch(fn, (cand_cols, wl_cols),
-                                            int(max_survivors), tracer)
+    n_workloads = np.shape(wl_cols)[0]
+    return dse_sweep_staged(
+        lambda: stage_cand_cols(cand_arrays, n_workloads, n_valid=n_valid,
+                                interpret=interpret),
+        wl_cols, sim=sim, max_power_w=max_power_w,
+        max_latency_s=max_latency_s, min_hbm_fit=min_hbm_fit,
+        max_survivors=max_survivors, interpret=interpret, tracer=tracer)

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 
 from repro.core import costmodel
 from repro.kernels.conv2d import conv2d_pallas
-from repro.kernels.dse_sweep import dse_sweep_reduced
+from repro.kernels.dse_sweep import (dse_sweep_reduced, stage_cand_cols,
+                                     dse_sweep_staged as _launch_staged)
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 from repro.telemetry.trace import NULL_TRACER
@@ -98,6 +99,14 @@ def conv2d(x, w, *, stride: int = 1, padding: str = "SAME", tile_h: int = 8,
                    interpret=_resolve_interpret(interpret))
 
 
+def _constraint_kw(constraint) -> dict:
+    if constraint is None:
+        return dict(max_power_w=None, max_latency_s=None, min_hbm_fit=True)
+    return dict(max_power_w=constraint.max_power_w,
+                max_latency_s=constraint.max_latency_s,
+                min_hbm_fit=constraint.min_hbm_fit)
+
+
 def dse_sweep(cand_arrays, wl_cols, *,
               sim: costmodel.SimConfig = costmodel.SimConfig(),
               constraint=None, max_survivors: int = 2048,
@@ -116,12 +125,34 @@ def dse_sweep(cand_arrays, wl_cols, *,
     — and compiled mode computes float32.  ``tracer`` times the host
     stages (``dse_sweep_reduced``).
     """
-    kw = dict(max_power_w=None, max_latency_s=None, min_hbm_fit=True)
-    if constraint is not None:
-        kw = dict(max_power_w=constraint.max_power_w,
-                  max_latency_s=constraint.max_latency_s,
-                  min_hbm_fit=constraint.min_hbm_fit)
     return dse_sweep_reduced(cand_arrays, wl_cols, sim=sim,
                              max_survivors=max_survivors, n_valid=n_valid,
                              interpret=_resolve_interpret(interpret),
-                             tracer=tracer, **kw)
+                             tracer=tracer, **_constraint_kw(constraint))
+
+
+def stage_dse_sweep(cand_arrays, n_workloads: int, *,
+                    n_valid: Optional[int] = None,
+                    interpret: Optional[bool] = None):
+    """``cand_arrays`` staged once for many ``dse_sweep_staged`` launches
+    with ``n_workloads`` rows: the ``stage_cand_cols`` matrix, copied to the
+    device when compiled.  That copy is the only explicit one; ``dse_sweep``
+    hands its host matrix to the jitted call."""
+    interpret = _resolve_interpret(interpret)
+    cand_cols = stage_cand_cols(cand_arrays, n_workloads, n_valid=n_valid,
+                                interpret=interpret)
+    return cand_cols if interpret else jax.device_put(cand_cols)
+
+
+def dse_sweep_staged(stage, wl_cols, *,
+                     sim: costmodel.SimConfig = costmodel.SimConfig(),
+                     constraint=None, max_survivors: int = 2048,
+                     interpret: Optional[bool] = None,
+                     tracer=NULL_TRACER) -> costmodel.SweepReduced:
+    """``dse_sweep`` on a staged tile: ``stage()`` returns the matrix
+    ``stage_dse_sweep`` made for ``wl_cols``' row count, and is called
+    inside the launch's ``pack`` span (``kernels.dse_sweep``)."""
+    return _launch_staged(stage, wl_cols, sim=sim,
+                          max_survivors=max_survivors,
+                          interpret=_resolve_interpret(interpret),
+                          tracer=tracer, **_constraint_kw(constraint))

@@ -173,6 +173,10 @@ from repro.core import costmodel as _costmodel              # noqa: E402
 #   predictor_only — deadline degradation: predictor-ranked, no exact sweep
 PROVENANCES = ("index_exact", "mini_campaign", "predictor_only")
 
+# whole-space candidate matrices an engine keeps staged, one per launch lane
+# count; group sizes up to six share one (``kernels.dse_sweep.block_lanes``)
+MAX_STAGED = 4
+
 
 @dataclasses.dataclass
 class SelectionQuery:
@@ -326,6 +330,12 @@ class SelectionEngine:
     sweep are lane-local, so batched answers are bitwise identical to
     sequential ones.
 
+    Staging: the whole space is the same candidate input to every exact
+    query that verifies it, so with the Pallas evaluator the engine stages
+    it once per launch lane count (on the device when compiled) and every
+    such launch reuses it; ``selection_stage_misses_total`` and
+    ``selection_stage_hits_total`` count the two outcomes.
+
     Observability: pass ``telemetry=`` to share a metrics registry / tracer
     with the caller (per-path ``selection_latency_s`` histograms of each
     query's time from ``submit`` to the end of the ``flush`` that answers
@@ -367,6 +377,12 @@ class SelectionEngine:
         self._next_qid = 0
         self._exact_ema_s: Optional[float] = None
         self._full_batch: Optional[_dse.CandidateBatch] = None
+        # launch lane count -> the staged whole-space matrix
+        self._staged: Dict[int, Any] = {}
+        self._c_stage_hits = self.telemetry.counter(
+            "selection_stage_hits_total")
+        self._c_stage_misses = self.telemetry.counter(
+            "selection_stage_misses_total")
         self._g_breaker = self.telemetry.gauge("selection_breaker_open")
         self._g_breaker.set(0.0)
         self.breaker = CircuitBreaker(
@@ -659,6 +675,28 @@ class SelectionEngine:
 
     # -- the exact fallback sweep -------------------------------------------
 
+    def _space_stage(self, ev: TileEvaluator, batch: _dse.CandidateBatch
+                     ) -> Callable[[], Any]:
+        """The ``stage`` of ``ev``'s launch over the whole space: the matrix
+        kept for its lane count (a hit), else ``ev.stage_tile(batch)``,
+        kept for the next launch of that count (a miss).  Called inside
+        the launch's ``pack`` span."""
+        lanes = ev.launch_lanes(len(batch))
+
+        def stage():
+            cols = self._staged.get(lanes)
+            if cols is not None:
+                self._c_stage_hits.inc()
+                return cols
+            self._c_stage_misses.inc()
+            cols = ev.stage_tile(batch)
+            if len(self._staged) >= MAX_STAGED:
+                del self._staged[next(iter(self._staged))]
+            self._staged[lanes] = cols
+            return cols
+
+        return stage
+
     def _mini_campaign(self, workloads: Sequence[_dse.Workload],
                        constraint: _dse.Constraint
                        ) -> Tuple[List[_dse.ParetoFrontier], np.ndarray]:
@@ -681,12 +719,15 @@ class SelectionEngine:
         ev = TileEvaluator(tagged, cfg, telemetry=self.telemetry)
         launches_before = ev._c_fused.value
         gidx = self._candidate_slice(workloads, constraint)
+        stage = None
         if gidx.size == len(self.space):
             batch = self._full_space_batch()
+            if ev.evaluator == "pallas":   # jit takes its columns unpacked
+                stage = self._space_stage(ev, batch)
         else:
             batch = _dse.CandidateBatch.from_candidates(
                 self.space.candidates_at(gidx))
-        tr = ev.reduce_tile(batch, 0)
+        tr = ev.reduce_tile(batch, 0, stage=stage)
         self._c_fused.inc(ev._c_fused.value - launches_before)
         fronts: List[_dse.ParetoFrontier] = []
         tel = self.telemetry

@@ -3,6 +3,8 @@ sizes, constraint-mask edge cases, merge_reduced == raw-merge identity
 (hypothesis property), campaign frontier identity and resume==fresh under
 ``evaluator="pallas"``, and the Pallas interpret auto-detection."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -12,9 +14,9 @@ except ImportError:  # pragma: no cover - exercised on bare installs
     from _hypothesis_stub import given, settings, st
 
 from repro.core import costmodel, dse
-from repro.dse_campaign import (Campaign, SliceVariant, SpaceSpec,
-                                StreamingFrontier, canonical_frontier,
-                                frontiers_identical)
+from repro.dse_campaign import (Campaign, CampaignConfig, SliceVariant,
+                                SpaceSpec, StreamingFrontier, TileEvaluator,
+                                canonical_frontier, frontiers_identical)
 from repro.hw import get_chip
 from repro.kernels import ops
 
@@ -216,6 +218,35 @@ def test_merge_reduced_idempotent_and_rejects_partial_overlap():
     with pytest.raises(ValueError, match="outside span"):
         fr.merge_reduced(_cands([9]), [1.0], [1.0], [9], span=(4, 8),
                          n_feasible=1, ref_energy_j=1.0, ref_latency_s=1.0)
+
+
+@pytest.mark.parametrize("n_workloads,lo,hi", [(1, 0, 20), (2, 5, 17)])
+def test_staged_launch_equals_one_call(n_workloads, lo, hi):
+    """Staging a tile, then launching on the staged matrix, gives the one
+    call's ``SweepReduced`` field by field, bitwise (interpret mode); the
+    stage runs once, inside the launch."""
+    spec = small_spec(chunk_size=32)
+    ev = TileEvaluator(WLS[:n_workloads], CampaignConfig(
+        space=spec, constraint=CONS, evaluator="pallas"))
+    batch = spec.slice(lo, hi, with_candidates=False)
+    arrays = ev.padded_tile_arrays(batch)
+    kw = dict(sim=ev.sim, constraint=CONS, max_survivors=4, interpret=True)
+    one = ops.dse_sweep(arrays, ev.wl_cols, n_valid=len(batch), **kw)
+    staged = ops.stage_dse_sweep(arrays, n_workloads, n_valid=len(batch),
+                                 interpret=True)
+    assert staged.shape[1] == ev.launch_lanes(len(batch))
+    calls = []
+    got = ops.dse_sweep_staged(lambda: calls.append(1) or staged,
+                               ev.wl_cols, **kw)
+    assert calls == [1]
+    for field in dataclasses.fields(costmodel.SweepReduced):
+        a, b = getattr(one, field.name), getattr(got, field.name)
+        assert np.asarray(a).dtype == np.asarray(b).dtype, field.name
+        assert np.array_equal(a, b), field.name
+    jit = TileEvaluator(WLS[:n_workloads], CampaignConfig(
+        space=spec, constraint=CONS, evaluator="jit"))
+    with pytest.raises(ValueError, match="pallas"):
+        jit.sweep_reduced(batch, lambda: staged)
 
 
 def test_compact_rows_device_matches_host():

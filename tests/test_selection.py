@@ -159,18 +159,83 @@ def test_index_hit_identity_on_all_cached_cells(tmp_path, offline):
     assert engine.stats["index_exact"] == len(CACHED)
 
 
-def test_novel_workload_fallback_parity(offline):
+def engine_with(index, **kw):
+    """A ``SelectionEngine`` over ``index`` with config fields overridden
+    (``evaluator="pallas"`` runs the kernel in interpret mode here)."""
+    return SelectionEngine(
+        index, SelectionEngine._config_from_index(index).replace(**kw))
+
+
+def stage_counts(engine):
+    """(misses, hits) of the engine's whole-space staging."""
+    tel = engine.telemetry
+    return (tel.counter("selection_stage_misses_total").value,
+            tel.counter("selection_stage_hits_total").value)
+
+
+@pytest.mark.parametrize("evaluator", ["jit", "pallas"])
+def test_novel_workload_fallback_parity(offline, evaluator):
     """A novel family's mini-campaign answer equals a standalone campaign
     on the same slice (here: the full serving space, swept independently
-    through the tile loop)."""
+    through the tile loop).  Only the Pallas evaluator stages the space."""
     _, _, index = offline
-    engine = SelectionEngine(index)
+    engine = engine_with(index, evaluator=evaluator)
     answer = engine.select(NOVEL)
     assert answer.provenance == "mini_campaign"
     assert answer.verified_gidx.size == len(engine.space)
     standalone = Campaign([NOVEL], engine.config).run()
     assert frontiers_identical(answer.frontier(),
                                standalone.frontiers[(NOVEL.arch, NOVEL.shape)])
+    assert stage_counts(engine) == ((1, 0) if evaluator == "pallas"
+                                    else (0, 0))
+
+
+def test_whole_space_queries_reuse_one_staged_matrix(offline):
+    """Repeated whole-space queries on one Pallas engine stage the space
+    once (one miss, then a hit per query) and answer bitwise as a fresh
+    engine's first query and as ``Campaign.run`` do."""
+    _, _, index = offline
+    queries = [NOVEL, wl("stablelm_1_6b", scale=0.41, chips=64, gb=0.2),
+               wl("mamba2_130m", scale=0.06, chips=16, gb=0.05), NOVEL]
+    engine = engine_with(index, evaluator="pallas")
+    for n, q in enumerate(queries, start=1):
+        answer = engine.select(q)
+        assert answer.verified_gidx.size == len(engine.space)
+        assert stage_counts(engine) == (1, n - 1)
+        fresh = engine_with(index, evaluator="pallas").select(q)
+        assert frontiers_identical(answer.frontier(), fresh.frontier())
+        standalone = Campaign([q], engine.config).run()
+        assert frontiers_identical(answer.frontier(),
+                                   standalone.frontiers[(q.arch, q.shape)])
+
+
+def test_group_of_eight_stages_its_own_lane_count(offline, monkeypatch):
+    """A flush of eight novel queries launches on another padded lane count
+    than one query does (blocks of 256 lanes for eight rows, 2,048 for one,
+    over 280 candidates: 512 lanes against 384), so it stages its own
+    matrix, and its answers equal eight sequential ones."""
+    from repro.kernels import dse_sweep
+    monkeypatch.setattr(dse_sweep, "BLOCK_ELEMS", 2048)
+    dse_sweep._jit_dse_sweep.cache_clear()
+    _, _, index = offline
+    engine = engine_with(index, evaluator="pallas",
+                         space=small_spec(freq_points=20))
+    n = len(engine.space)
+    assert (dse_sweep.padded_lanes(n, 8), dse_sweep.padded_lanes(n, 1)) == (
+        512, 384)
+    novel = [wl(scale=1.01 + 0.02 * i) for i in range(8)]
+    try:
+        for w in novel:
+            engine.submit(w)
+        batched = engine.flush()
+        assert engine.fused_launches == 1
+        assert stage_counts(engine) == (1, 0)
+        for i, w in enumerate(novel):
+            solo = engine.select(w)
+            assert frontiers_identical(batched[i].frontier(), solo.frontier())
+            assert stage_counts(engine) == (2, i)
+    finally:
+        dse_sweep._jit_dse_sweep.cache_clear()
 
 
 def test_constraint_override_forces_exact_path(offline):
@@ -205,17 +270,21 @@ def test_deadline_exceeded_degrades_to_predictor_only(offline):
     assert set(engine.stats) >= set(PROVENANCES)
 
 
-def test_predictor_pruned_slice_is_verified_exactly(offline):
+@pytest.mark.parametrize("evaluator", ["jit", "pallas"])
+def test_predictor_pruned_slice_is_verified_exactly(offline, evaluator):
     """With predictors, the fallback verifies a pruned slice; the served
-    frontier equals a direct exact evaluation of that same slice."""
+    frontier equals a direct exact evaluation of that same slice.  A slice
+    changes per query, so it is never staged: no hit, no miss."""
     _, _, index = offline
     cfg = SelectionEngine._config_from_index(index).replace(
-        power_model=StubModel(40.0), cycles_model=StubModel(1e9))
+        evaluator=evaluator, power_model=StubModel(40.0),
+        cycles_model=StubModel(1e9))
     engine = SelectionEngine(index, cfg, verify_top=16)
     answer = engine.select(NOVEL)
     assert answer.provenance == "mini_campaign"
     gidx = answer.verified_gidx
     assert 0 < gidx.size < len(engine.space)
+    assert stage_counts(engine) == (0, 0)
     ev = TileEvaluator([NOVEL], engine.config)
     batch = dse.CandidateBatch.from_candidates(
         engine.space.candidates_at(gidx))
