@@ -13,7 +13,9 @@ Latency model:
   t_coll -- topology-aware when the candidate's mesh is known: the collective
   payload splits into a data-parallel share (hierarchical ring all-reduce over
   the pod x data axes) and a model-parallel share (all-gather/reduce-scatter
-  on the model axis), each axis costing
+  on the model axis) -- by the census's own per-axis split where the
+  workload carries one (``coll_model_share``), else by
+  ``SimConfig.coll_model_frac`` -- each axis costing
 
       t_axis = bytes_axis * (k - 1)/k / (ici_bw * links_axis)
                + 2 * (k - 1) * hop_s
@@ -47,8 +49,9 @@ from repro.telemetry.trace import NULL_TRACER
 # default fraction of the collective payload attributed to model-parallel
 # collectives (activation all-gather/reduce-scatter on the model axis); the
 # remainder is the data-parallel all-reduce share.  The split happens in ONE
-# place (``collective_payload``), always from the simulating ``SimConfig``'s
-# ``coll_model_frac`` — analyses carry only the un-split payload.
+# place (``collective_payload``): by the analysis's ``coll_model_share`` --
+# the census's model-axis share of its wire bytes -- where it carries one,
+# else by the simulating ``SimConfig``'s ``coll_model_frac``.
 COLL_MODEL_FRAC = 0.5
 
 # bump when the cost model's arithmetic changes on purpose: the CI frontier
@@ -59,7 +62,9 @@ COLL_MODEL_FRAC = 0.5
 # 1 = mesh-agnostic links_used; 2 = topology-aware collectives;
 # 3 = SimConfig.links_used removed (mesh-less simulation is the fixed
 #     MESHLESS_LINKS approximation, no longer a config knob)
-SIM_MODEL_VERSION = 3
+# 4 = a workload's census may carry its model-axis share
+#     (``coll_model_share``), which replaces ``coll_model_frac`` for it
+SIM_MODEL_VERSION = 4
 
 # link count of the fixed mesh-less approximation: censuses simulated
 # without a candidate mesh (dry-run base pods, offload slices, rooflines)
@@ -114,14 +119,23 @@ def _raw_payload(analysis: Dict, n_chips, xp):
     return wire / ring
 
 
+def model_share(wire_bytes_by_axis: Dict) -> Optional[float]:
+    """A census's model-axis share of its wire bytes, model / (data +
+    model), from the dry-run's ``wire_bytes_by_axis``; None for a census
+    that moves no bytes."""
+    data, model = wire_bytes_by_axis["data"], wire_bytes_by_axis["model"]
+    return model / (data + model) if data + model > 0 else None
+
+
 def collective_payload(analysis: Dict, n_chips, frac: float, xp=np):
     """(data_bytes, model_bytes) collective payload split for a candidate.
 
-    The ONLY place the data/model split happens, so the simulating
-    ``SimConfig.coll_model_frac`` is always honored.  Identical IEEE
-    expressions in scalar and array form, so every simulate variant splits
-    bitwise the same."""
+    The ONLY place the data/model split happens: by the analysis's
+    ``coll_model_share`` where it carries one, else by the simulating
+    ``SimConfig.coll_model_frac``.  Identical IEEE expressions in scalar
+    and array form, so every simulate variant splits bitwise the same."""
     payload = _raw_payload(analysis, n_chips, xp)
+    frac = analysis.get("coll_model_share", frac)
     return payload * (1.0 - frac), payload * frac
 
 
@@ -376,14 +390,15 @@ def scale_census(base_analysis: Dict, base_chips, n_chips, xp=np) -> Dict:
     path, so the float64 variants agree bitwise with the scalar oracle.
     flops/bytes scale ~1/chips; collective bytes ride the ring factor; the
     emitted ``coll_payload_bytes`` un-applies the base census's global ring
-    factor so the topology-aware simulator can split it per mesh axis.
+    factor so the topology-aware simulator can split it per mesh axis; a
+    ``coll_model_share`` passes through unscaled.
     """
     bc = xp.asarray(base_chips) * 1.0
     nc = xp.asarray(n_chips) * 1.0
     r = bc / nc
     ring_base = xp.maximum((bc - 1.0) / bc, 1e-9)
     ring = xp.where(nc > 1, ((nc - 1.0) / nc) / ring_base, 0.0)
-    return {
+    out = {
         "flops": xp.asarray(base_analysis["flops"]) * r,
         "hbm_bytes": xp.asarray(base_analysis["hbm_bytes"]) * r,
         "collective_bytes":
@@ -392,6 +407,9 @@ def scale_census(base_analysis: Dict, base_chips, n_chips, xp=np) -> Dict:
         "coll_payload_bytes":
             xp.asarray(base_analysis["wire_bytes"]) * r / ring_base,
     }
+    if "coll_model_share" in base_analysis:
+        out["coll_model_share"] = xp.asarray(base_analysis["coll_model_share"])
+    return out
 
 
 # --- Fused sweep reduction (per-tile skyline pre-reduction) -------------------
@@ -410,9 +428,21 @@ def scale_census(base_analysis: Dict, base_chips, n_chips, xp=np) -> Dict:
 # capacity the feasibility check reads
 SWEEP_GATHER_FIELDS = SIM_GATHER_FIELDS + ("hbm_bytes",)
 
-# per-workload scalar column order of the packed [W, 6] workload matrix
+# per-workload scalar column order of the packed [W, 7] workload matrix; a
+# workload without a census split packs ``SimConfig.coll_model_frac`` as its
+# ``coll_model_share`` (``wl_row``)
 WL_COLS = ("flops", "hbm_bytes", "collective_bytes", "wire_bytes",
-           "base_chips", "state_gb_per_device")
+           "base_chips", "state_gb_per_device", "coll_model_share")
+
+
+def wl_row(base_analysis: Dict, base_chips, state_gb_per_device,
+           frac: float) -> list:
+    """One workload's ``WL_COLS`` row: its census scalars, and its
+    ``coll_model_share`` or ``frac`` where it carries none."""
+    return [base_analysis["flops"], base_analysis["hbm_bytes"],
+            base_analysis["collective_bytes"], base_analysis["wire_bytes"],
+            base_chips, state_gb_per_device,
+            base_analysis.get("coll_model_share", frac)]
 
 
 def _cummin(x, xp):
@@ -634,7 +664,7 @@ def sweep_workloads_reduced_jit(wl_cols, chip_cols: Dict, n_chips, freq_mhz,
     precision tier); the Pallas kernel path (``repro.kernels.dse_sweep``)
     shares every helper and runs float64 in interpret mode.  ``chip_cols``
     needs the ``SWEEP_GATHER_FIELDS`` columns; ``wl_cols`` is the packed
-    [W, 6] ``WL_COLS`` matrix; the inputs cross to the device inside the
+    [W, 7] ``WL_COLS`` matrix; the inputs cross to the device inside the
     jitted call.  ``tracer`` (a ``SpanTracer`` or ``Telemetry``) times the
     host stages (``run_reduced_launch``).
     """
@@ -696,11 +726,12 @@ def _jit_simulate_batch(sim: SimConfig, with_mesh: bool):
     import jax.numpy as jnp
 
     if with_mesh:
-        def run(flops, hbm_bytes, payload, chip_idx, n_chips,
+        def run(flops, hbm_bytes, payload, share, chip_idx, n_chips,
                 freq_mhz, mesh_pod, mesh_data, mesh_model):
             batch = simulate_batch(
                 {"flops": flops, "hbm_bytes": hbm_bytes,
-                 "coll_payload_bytes": payload, "wire_bytes": payload},
+                 "coll_payload_bytes": payload, "wire_bytes": payload,
+                 "coll_model_share": share},
                 chip_idx, n_chips, freq_mhz, sim=sim, xp=jnp,
                 mesh_pod=mesh_pod, mesh_data=mesh_data, mesh_model=mesh_model)
             return dataclasses.asdict(batch)
@@ -726,8 +757,8 @@ def simulate_batch_jit(analysis: Dict, chip_idx, n_chips, freq_mhz,
     numpy path's exact match.  Passing ``mesh_data``/``mesh_model`` (and
     optionally ``mesh_pod``) selects the topology-aware collective model;
     the un-split payload is derived in float64 numpy BEFORE entering the
-    jit, then split in-trace by ``sim.coll_model_frac`` like every other
-    path.
+    jit, then split in-trace by the analysis's ``coll_model_share`` (else
+    ``sim.coll_model_frac``) like every other path.
     """
     if mesh_model is not None:
         mesh_model = np.asarray(mesh_model, np.int32)
@@ -735,8 +766,9 @@ def simulate_batch_jit(analysis: Dict, chip_idx, n_chips, freq_mhz,
         mesh_pod = (np.ones_like(mesh_model) if mesh_pod is None
                     else np.asarray(mesh_pod, np.int32))
         payload = _raw_payload(analysis, n_chips, np)
+        share = analysis.get("coll_model_share", sim.coll_model_frac)
         out = _jit_simulate_batch(sim, True)(
-            analysis["flops"], analysis["hbm_bytes"], payload,
+            analysis["flops"], analysis["hbm_bytes"], payload, share,
             np.asarray(chip_idx, np.int32), n_chips, freq_mhz,
             mesh_pod, mesh_data, mesh_model)
     else:

@@ -154,12 +154,13 @@ def _scale_analysis(base_analysis: Dict, base_chips: int, cand: Candidate) -> Di
     grow with ring size: x (n-1)/n relative to base ring.  Also emits
     ``coll_payload_bytes`` — the payload with the base census's global ring
     factor un-applied — which the topology-aware simulator splits across
-    mesh axes by its ``SimConfig.coll_model_frac``.
+    mesh axes by the census's ``coll_model_share`` (passed through where
+    the census carries one) or its ``SimConfig.coll_model_frac``.
     """
     r = base_chips / cand.n_chips
     nb, nc = base_chips, cand.n_chips
     ring = ((nc - 1) / nc) / max((nb - 1) / nb, 1e-9) if nc > 1 else 0.0
-    return {
+    out = {
         "flops": base_analysis["flops"] * r,
         "hbm_bytes": base_analysis["hbm_bytes"] * r,
         "collective_bytes": base_analysis["collective_bytes"] * r * ring,
@@ -167,6 +168,9 @@ def _scale_analysis(base_analysis: Dict, base_chips: int, cand: Candidate) -> Di
         "coll_payload_bytes":
             base_analysis["wire_bytes"] * r / max((nb - 1) / nb, 1e-9),
     }
+    if "coll_model_share" in base_analysis:
+        out["coll_model_share"] = base_analysis["coll_model_share"]
+    return out
 
 
 def _scale_analysis_batch(base_analysis: Dict, base_chips,
@@ -463,9 +467,25 @@ def fast_path_search(arch: str, shape_name: str, power_model, cycles_model,
 # --- Multi-objective / multi-workload sweep -----------------------------------
 
 
+def census_analysis(hxa: Dict) -> Dict:
+    """A ``Workload.base_analysis`` from a dry-run artifact's ``hxa``
+    block: the census scalars the sweep scales, and the census's
+    ``coll_model_share`` where the artifact splits its wire bytes by mesh
+    axis (and they are not zero)."""
+    out = {k: hxa[k] for k in ("flops", "hbm_bytes", "collective_bytes",
+                               "wire_bytes")}
+    share = (costmodel.model_share(hxa["wire_bytes_by_axis"])
+             if "wire_bytes_by_axis" in hxa else None)
+    if share is not None:
+        out["coll_model_share"] = share
+    return out
+
+
 @dataclasses.dataclass(frozen=True)
 class Workload:
-    """One (arch, shape) cell to sweep: its compiled census + footprint."""
+    """One (arch, shape) cell to sweep: its compiled census + footprint.
+    ``base_analysis`` may carry ``coll_model_share``, the census's
+    model-axis share of its collective wire bytes."""
 
     arch: str
     shape: str
@@ -548,6 +568,9 @@ def pareto_search(workloads: Union[Workload, Sequence[Workload]],
     rep = lambda vals: np.repeat(np.asarray(vals, np.float64), n)
     base = {k: rep([wl.base_analysis[k] for wl in workloads])
             for k in ("flops", "hbm_bytes", "collective_bytes", "wire_bytes")}
+    base["coll_model_share"] = rep(
+        [wl.base_analysis.get("coll_model_share", costmodel.COLL_MODEL_FRAC)
+         for wl in workloads])
     base_chips = rep([wl.base_chips for wl in workloads])
     ana = _scale_analysis_batch(base, base_chips, tile(batch.n_chips))
     gathered = ({k: tile(batch.chip_cols[k])

@@ -25,6 +25,14 @@ Cost conventions (documented knobs, not truth claims):
                    interiors are free — they never round-trip to HBM)
   * collectives:   operand bytes (the §Roofline contract), plus a modeled
                    "wire bytes" using ring formulas for reporting.
+
+Mesh axes: every collective's replica groups (or permute pairs) are kept
+with its wire bytes, and ``split_wire_bytes`` classifies each group set
+against the census mesh — a group varying only along ``model`` rides the
+model axis, one varying only along ``data`` the data axis, and one spanning
+several axes (or the ``pod`` axis) is charged to ``data``, where the cost
+model's hierarchical data -> pod ring runs it, and also reported on its own
+as ``both_axes``.  A group the parser cannot read is an error.
 """
 
 from __future__ import annotations
@@ -33,6 +41,10 @@ import dataclasses
 import re
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
+
+import numpy as np
+
+from repro.telemetry.trace import NULL_TRACER
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "s32": 4, "u32": 4,
@@ -225,6 +237,9 @@ class Census:
     op_counts: Dict[str, float] = dataclasses.field(default_factory=dict)
     hbm_by_opcode: Dict[str, float] = dataclasses.field(default_factory=dict)
     collectives: Dict[str, Dict[str, float]] = dataclasses.field(default_factory=dict)
+    # wire bytes by the collective's device-group attribute (its
+    # ``replica_groups=...`` or ``source_target_pairs=...`` text)
+    wire_by_groups: Dict[str, float] = dataclasses.field(default_factory=dict)
     loops: List[Dict] = dataclasses.field(default_factory=list)
 
     def _hbm(self, opcode: str, nbytes: float):
@@ -245,17 +260,101 @@ class Census:
                                                    "wire_bytes": 0.0})
             for kk in slot:
                 slot[kk] += v.get(kk, 0.0) * mult
+        for k, v in other.wire_by_groups.items():
+            self.wire_by_groups[k] = self.wire_by_groups.get(k, 0.0) + v * mult
         self.loops.extend(other.loops)
 
 
-def _group_size(attrs: str) -> int:
-    m = re.search(r"replica_groups=\[(\d+),(\d+)\]", attrs)
+_IOTA_GROUPS_RE = re.compile(
+    r"replica_groups=\[(\d+),(\d+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?")
+_LIST_GROUPS_RE = re.compile(
+    r"(replica_groups|source_target_pairs)=\{((?:\{[\d,]*\},?)*)\}")
+
+
+def group_attr(attrs: str) -> str:
+    """The device-group text of a collective's attributes: its iota or
+    explicit ``replica_groups`` or its ``source_target_pairs``.  A
+    collective without one cannot be priced or placed on a mesh axis, so
+    it is an error."""
+    m = _IOTA_GROUPS_RE.search(attrs) or _LIST_GROUPS_RE.search(attrs)
+    if m is None:
+        raise ValueError(f"collective without readable replica groups: "
+                         f"{attrs[:200]!r}")
+    return m.group(0)
+
+
+def device_groups(group_text: str,
+                  n_devices: Optional[int] = None) -> List[List[int]]:
+    """The device-id groups of a ``group_attr`` text.  The iota form
+    ``[G,S]<=[dims]T(perm)`` is ``arange(prod(dims))`` reshaped to
+    ``dims``, transposed by ``perm`` and cut into G groups of S; explicit
+    groups are read as written (permute pairs as two-device groups); the
+    empty ``replica_groups={}`` is one group of all ``n_devices``."""
+    m = _IOTA_GROUPS_RE.fullmatch(group_text)
     if m:
-        return int(m.group(2))
-    m = re.search(r"replica_groups=\{\{([\d,]+)\}", attrs)
-    if m:
-        return len(m.group(1).split(","))
-    return 2
+        g, size = int(m.group(1)), int(m.group(2))
+        dims = [int(d) for d in m.group(3).split(",")]
+        ids = np.arange(int(np.prod(dims))).reshape(dims)
+        if m.group(4):
+            ids = ids.transpose([int(p) for p in m.group(4).split(",")])
+        return ids.reshape(g, size).tolist()
+    m = _LIST_GROUPS_RE.fullmatch(group_text)
+    if m is None:
+        raise ValueError(f"unreadable device groups {group_text!r}")
+    groups = [[int(i) for i in grp.split(",") if i]
+              for grp in re.findall(r"\{([\d,]*)\}", m.group(2))]
+    if not groups and m.group(1) == "replica_groups":
+        if n_devices is None:
+            raise ValueError("replica_groups={} (all devices) needs the "
+                             "device count")
+        groups = [list(range(n_devices))]
+    return groups
+
+
+def _group_size(group_text: str, n_devices: Optional[int] = None) -> int:
+    """Devices per group (2 for permute pairs): the ring size of the wire
+    factor."""
+    groups = device_groups(group_text, n_devices)
+    return len(groups[0]) if groups else 0
+
+
+def group_axes(group_text: str, mesh: Dict[str, int]) -> Tuple[str, ...]:
+    """The mesh axes along which the members of some group differ.
+    ``mesh`` maps axis names to extents in the mesh's own order; device
+    ids are row-major positions in it.  An id outside the mesh is an
+    error."""
+    names, extents = list(mesh), list(mesh.values())
+    n = int(np.prod(extents))
+    groups = device_groups(group_text, n)
+    varying = np.zeros(len(names), bool)
+    for grp in groups:
+        ids = np.asarray(grp, np.int64)
+        if ids.size and (ids.min() < 0 or ids.max() >= n):
+            raise ValueError(f"device ids of {group_text!r} lie outside "
+                             f"the {n}-device mesh {mesh}")
+        if ids.size > 1:
+            coords = np.stack(np.unravel_index(ids, extents))
+            varying |= (coords != coords[:, :1]).any(axis=1)
+    return tuple(a for a, v in zip(names, varying) if v)
+
+
+def split_wire_bytes(wire_by_groups: Dict[str, float],
+                     mesh: Dict[str, int]) -> Dict[str, float]:
+    """Wire bytes per mesh axis: ``model`` for groups that vary only along
+    the model axis, ``data`` for all others (the data axis alone, several
+    axes, or the pod axis), and ``both_axes`` for the part of ``data``
+    whose groups span several axes or the pod axis.  ``data + model``
+    equals the census's ``wire_bytes``."""
+    out = {"data": 0.0, "model": 0.0, "both_axes": 0.0}
+    for text, wire in wire_by_groups.items():
+        axes = group_axes(text, mesh)
+        if axes == ("model",):
+            out["model"] += wire
+            continue
+        out["data"] += wire
+        if len(axes) > 1 or "pod" in axes:
+            out["both_axes"] += wire
+    return out
 
 
 def _wire_factor(opcode: str, n: int) -> float:
@@ -285,11 +384,13 @@ def _passes_through_bf16(src: Op, comps: Dict[str, List[Op]]) -> bool:
 
 def census_computation(name: str, comps: Dict[str, List[Op]],
                        _memo: Optional[dict] = None,
-                       trips_ctx: int = 1) -> Census:
+                       trips_ctx: int = 1,
+                       n_devices: Optional[int] = None) -> Census:
     """trips_ctx: trip count of the IMMEDIATELY enclosing while loop.  A
     fusion that dynamic-slices a stacked buffer inside a T-trip loop touches
     a 1/T window of it per iteration — the HyPA-style control-flow-aware
-    traffic attribution."""
+    traffic attribution.  ``n_devices`` sizes an all-devices
+    ``replica_groups={}``."""
     memo = _memo if _memo is not None else {}
     key = (name, trips_ctx)
     if key in memo:
@@ -311,10 +412,12 @@ def census_computation(name: str, comps: Dict[str, List[Op]],
                 src = producers.get(op.operand_names[0])
                 if src is not None and _passes_through_bf16(src, comps):
                     b *= 0.5
-            n = _group_size(op.attrs)
+            groups = group_attr(op.attrs)
+            n = _group_size(groups, n_devices)
             wire = b * _wire_factor(op.opcode, n)
             c.collective_bytes += b
             c.wire_bytes += wire
+            c.wire_by_groups[groups] = c.wire_by_groups.get(groups, 0.0) + wire
             slot = c.collectives.setdefault(op.opcode,
                                             {"count": 0.0, "bytes": 0.0, "wire_bytes": 0.0})
             slot["count"] += 1
@@ -331,14 +434,17 @@ def census_computation(name: str, comps: Dict[str, List[Op]],
                 cond = m.group(1)
             trips = _trip_count(comps.get(cond, [])) if cond else 1
             if body:
-                sub = census_computation(body, comps, memo, trips_ctx=trips)
+                sub = census_computation(body, comps, memo, trips_ctx=trips,
+                                         n_devices=n_devices)
                 c.add(sub, mult=trips)
                 c.loops.append({"body": body, "trips": trips,
                                 "body_flops": sub.flops})
         elif op.opcode in ("fusion", "call", "conditional"):
             sub_counts = Census()
             for callee in op.calls:
-                sub = census_computation(callee, comps, memo, trips_ctx=trips_ctx)
+                sub = census_computation(callee, comps, memo,
+                                         trips_ctx=trips_ctx,
+                                         n_devices=n_devices)
                 c.add(sub)
                 sub_counts.add(sub)
             if op.opcode == "fusion":
@@ -416,14 +522,20 @@ def _entry_name(comps: Dict[str, List[Op]], text: str) -> str:
     return max(comps, key=lambda k: len(comps[k]))
 
 
-def analyze_hlo_text(text: str) -> dict:
-    """Full HxA analysis of one compiled HLO module (per-device numbers)."""
+def analyze_hlo_text(text: str, mesh: Optional[Dict[str, int]] = None,
+                     tracer=NULL_TRACER) -> dict:
+    """Full HxA analysis of one compiled HLO module (per-device numbers).
+
+    With ``mesh`` (axis name -> extent, in the mesh's order) the result
+    also holds ``wire_bytes_by_axis`` (``split_wire_bytes``), classified
+    inside a ``census_axes`` span of ``tracer``."""
     comps = parse_module(text)
     entry = _entry_name(comps, text)
+    n_devices = int(np.prod(list(mesh.values()))) if mesh else None
     # fusions called inside while bodies are memoized once; the recursion in
     # census_computation handles nesting, so we only walk from the entry.
-    census = census_computation(entry, comps, {})
-    return {
+    census = census_computation(entry, comps, {}, n_devices=n_devices)
+    out = {
         "entry": entry,
         "flops": census.flops,
         "hbm_bytes": census.hbm_bytes,
@@ -437,3 +549,8 @@ def analyze_hlo_text(text: str) -> dict:
         "loops": census.loops[:20],
         "n_computations": len(comps),
     }
+    if mesh:
+        with tracer.span("census_axes", groups=len(census.wire_by_groups)):
+            out["wire_bytes_by_axis"] = split_wire_bytes(
+                census.wire_by_groups, mesh)
+    return out

@@ -288,7 +288,10 @@ class TileEvaluator:
     registry/tracer with the caller, or omit it for a private
     ``NullTelemetry`` (counters still count, tracing is free).
     ``evaluator_overflows_total`` counts workloads whose screened survivors
-    overflowed ``max_survivors`` and were reduced on the host instead.
+    overflowed ``max_survivors`` and were reduced on the host instead;
+    ``evaluator_split_evals_total`` counts the candidate x workload
+    evaluations priced with a census split (``coll_model_share``), beside
+    ``evaluator_candidates_total``, which counts them all.
     """
 
     def __init__(self, workloads: Sequence[dse.Workload], config=None,
@@ -316,6 +319,9 @@ class TileEvaluator:
         self._c_fused = self.telemetry.counter("evaluator_fused_launches_total")
         self._c_candidates = self.telemetry.counter(
             "evaluator_candidates_total")
+        self._c_split = self.telemetry.counter("evaluator_split_evals_total")
+        self._n_split = sum("coll_model_share" in wl.base_analysis
+                            for wl in self.workloads)
         self._c_overflows = self.telemetry.counter(
             "evaluator_overflows_total")
 
@@ -363,13 +369,13 @@ class TileEvaluator:
 
     @functools.cached_property
     def wl_cols(self) -> np.ndarray:
-        """Packed [W, len(WL_COLS)] per-workload scalar matrix (cached)."""
+        """Packed [W, len(WL_COLS)] per-workload scalar matrix (cached); a
+        workload without a census split packs ``sim.coll_model_frac``."""
         return np.asarray(
-            [[wl.base_analysis["flops"], wl.base_analysis["hbm_bytes"],
-              wl.base_analysis["collective_bytes"],
-              wl.base_analysis["wire_bytes"], wl.base_chips,
-              wl.state_gb_per_device] for wl in self.workloads],
-            np.float64)
+            [costmodel.wl_row(wl.base_analysis, wl.base_chips,
+                              wl.state_gb_per_device,
+                              self.sim.coll_model_frac)
+             for wl in self.workloads], np.float64)
 
     def padded_tile_arrays(self, batch: dse.CandidateBatch) -> Dict:
         """The tile's packed columns padded to ``chunk_size`` with a validity
@@ -569,6 +575,7 @@ class TileEvaluator:
             sample_energy=tuple(samp_e) if lidx is not None else None,
             sample_latency=tuple(samp_l) if lidx is not None else None)
         self._c_candidates.inc(n * len(self.workloads))
+        self._c_split.inc(n * self._n_split)
         return tr
 
 
@@ -679,9 +686,7 @@ class Campaign:
                 arch, f"{shape}:{pod}")
             seen[key] = dse.Workload(
                 arch=key[0], shape=key[1],
-                base_analysis={k: art["hxa"][k] for k in
-                               ("flops", "hbm_bytes", "collective_bytes",
-                                "wire_bytes")},
+                base_analysis=dse.census_analysis(art["hxa"]),
                 base_chips=art["roofline"]["n_chips"],
                 state_gb_per_device=art["memory"]["state_gb_per_device"])
         return cls(list(seen.values()), config, **kwargs)

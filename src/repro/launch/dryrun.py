@@ -16,6 +16,10 @@ Usage:
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
+# The TPU runtime pins a host staging buffer as it starts: 4 GiB by default,
+# which took 7-9.5 s of each process's start on one v5e VM.  This program's
+# host-device copies are tens of MB at most; a caller's own setting wins.
+os.environ.setdefault("TPU_PREMAPPED_BUFFER_SIZE", str(256 << 20))
 
 import argparse          # noqa: E402
 import json              # noqa: E402
@@ -84,7 +88,9 @@ def reanalyze(tag: str) -> dict:
         art = json.load(f)
     with gzip.open(os.path.join(art_dir(), "hlo", tag + ".hlo.gz"), "rt") as f:
         text = f.read()
-    analysis = hxa.analyze_hlo_text(text)
+    mesh_shape = tuple(int(d) for d in art["mesh"].split("x"))
+    axes = ("pod", "data", "model")[-len(mesh_shape):]
+    analysis = hxa.analyze_hlo_text(text, mesh=dict(zip(axes, mesh_shape)))
     analysis["hbm_bytes_xla"] = analysis["hbm_bytes"]
     cfg_d = art["config"]
     from repro.configs.base import get_config as _gc
@@ -103,10 +109,10 @@ def reanalyze(tag: str) -> dict:
     chip = get_chip()
     art["hxa"] = {k: analysis[k] for k in
                   ("flops", "hbm_bytes", "hbm_bytes_xla", "collective_bytes",
-                   "wire_bytes", "op_counts", "hbm_by_opcode", "collectives",
-                   "loops", "n_computations", "kernel_substitution")}
+                   "wire_bytes", "wire_bytes_by_axis", "op_counts",
+                   "hbm_by_opcode", "collectives", "loops", "n_computations",
+                   "kernel_substitution")}
     art["roofline"] = costmodel.roofline_terms(analysis, chip, n_chips)
-    mesh_shape = tuple(int(d) for d in art["mesh"].split("x"))
     art["sim"] = costmodel.simulate(analysis, chip, n_chips,
                                     mesh=mesh_shape).as_dict()
     hlo_flops_global = analysis["flops"] * n_chips

@@ -22,6 +22,7 @@ from repro.hw import get_chip
 from repro.models import api
 from repro.models.dist import make_dist
 from repro import optim
+from repro.telemetry.trace import NULL_TRACER
 
 _COERCE = {
     "remat": str, "capacity_factor": float, "optimizer": str, "dtype": str,
@@ -139,7 +140,9 @@ def _cost_dict(compiled) -> Dict:
 def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh,
                overrides: Optional[Dict[str, str]] = None,
                chip_name: str = "tpu-v5e",
-               include_hlo: bool = False) -> Dict:
+               include_hlo: bool = False, tracer=NULL_TRACER) -> Dict:
+    """Lower, compile and census one cell on ``mesh``; ``tracer`` times
+    the classification of the collectives by mesh axis (``census_axes``)."""
     cfg = apply_overrides(cfg, overrides or {})
     dist = make_dist(mesh)
     model = api.build_model(cfg)
@@ -175,7 +178,9 @@ def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh,
 
     compiled = lowered.compile()
     hlo_text = compiled.as_text()
-    analysis = hxa.analyze_hlo_text(hlo_text)
+    analysis = hxa.analyze_hlo_text(
+        hlo_text, mesh=dict(zip(mesh.axis_names, mesh.devices.shape)),
+        tracer=tracer)
     analysis["hbm_bytes_xla"] = analysis["hbm_bytes"]
     subst = kernel_substitution(cfg, shape, n_chips,
                                 dict(zip(mesh.axis_names,
@@ -201,8 +206,9 @@ def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh,
         "cost": _cost_dict(compiled),
         "hxa": {k: analysis[k] for k in
                 ("flops", "hbm_bytes", "hbm_bytes_xla", "collective_bytes",
-                 "wire_bytes", "op_counts", "hbm_by_opcode", "collectives",
-                 "loops", "n_computations", "kernel_substitution")},
+                 "wire_bytes", "wire_bytes_by_axis", "op_counts",
+                 "hbm_by_opcode", "collectives", "loops", "n_computations",
+                 "kernel_substitution")},
         "roofline": roof,
         "sim": sim.as_dict(),
         "model_flops": mf,

@@ -8,12 +8,14 @@ family to its exact offline frontier, so a selection query on a known
 family is a lookup — no sweep, no device, and the answer is *identical* to
 the offline campaign pick by construction.
 
-A workload family is keyed by its HxA-census feature vector — the same six
-``costmodel.WL_COLS`` scalars (flops, hbm_bytes, collective_bytes,
-wire_bytes, base_chips, state_gb_per_device) the fused sweep packs per
-workload — so "same family" means "the cost model cannot tell them apart".
-Lookup is O(log n): families are sorted by a 1-D projection of their
-normalized log-features, a query binary-searches the projection
+A workload family is keyed by its HxA-census feature vector — the six
+census scalars of ``costmodel.WL_COLS`` (flops, hbm_bytes,
+collective_bytes, wire_bytes, base_chips, state_gb_per_device), and a
+seventh, the census's ``coll_model_share``, only for a family that carries
+one — so "same family" means "the cost model cannot tell them apart".  A
+key with a share never matches one without.  Lookup is O(log n): families
+are sorted by a 1-D projection of their normalized log-features (the six
+census scalars), a query binary-searches the projection
 (``np.searchsorted``) and scans a constant-size window around the
 insertion point with the full distance.  An exact hit (relative tolerance
 ``match_rtol``) always lands inside the window because equal vectors have
@@ -50,14 +52,21 @@ INDEX_SCHEMA_VERSION = 1
 LOOKUP_WINDOW = 8
 
 
+# family-key components the lookup projection and distance read: the census
+# scalars every family has (a share, where present, is matched exactly)
+N_FEATURES = 6
+
+
 def family_key(wl: dse.Workload) -> np.ndarray:
     """The workload's family feature vector — ``costmodel.WL_COLS`` order,
-    float64.  One definition shared by index build and query so the two
-    cannot disagree on what a family is."""
-    return np.asarray(
-        [wl.base_analysis["flops"], wl.base_analysis["hbm_bytes"],
-         wl.base_analysis["collective_bytes"], wl.base_analysis["wire_bytes"],
-         wl.base_chips, wl.state_gb_per_device], np.float64)
+    float64; the ``coll_model_share`` column only where the workload
+    carries a census split.  One definition shared by index build and query
+    so the two cannot disagree on what a family is."""
+    share = wl.base_analysis.get("coll_model_share")
+    row = costmodel.wl_row(wl.base_analysis, wl.base_chips,
+                           wl.state_gb_per_device, share)
+    return np.asarray(row if share is not None else row[:N_FEATURES],
+                      np.float64)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -108,9 +117,10 @@ class FrontierIndex:
     def _build_lookup(self) -> None:
         n = len(self.entries)
         feats = np.log1p(np.abs(np.stack(
-            [e.family for e in self.entries]))) if n else np.zeros((0, 6))
-        lo = feats.min(axis=0) if n else np.zeros(6)
-        span = (feats.max(axis=0) - lo) if n else np.ones(6)
+            [e.family[:N_FEATURES] for e in self.entries]))) if n \
+            else np.zeros((0, N_FEATURES))
+        lo = feats.min(axis=0) if n else np.zeros(N_FEATURES)
+        span = (feats.max(axis=0) - lo) if n else np.ones(N_FEATURES)
         span = np.where(span > 0, span, 1.0)
         self._feat_lo, self._feat_span = lo, span
         self._feats = (feats - lo) / span          # [n, 6] in [0, 1]
@@ -119,7 +129,8 @@ class FrontierIndex:
         self._proj = proj[self._order]
 
     def _normalize(self, key: np.ndarray) -> np.ndarray:
-        return (np.log1p(np.abs(key)) - self._feat_lo) / self._feat_span
+        return ((np.log1p(np.abs(key[:N_FEATURES])) - self._feat_lo)
+                / self._feat_span)
 
     def _window(self, key: np.ndarray) -> np.ndarray:
         """Entry positions (into ``self.entries``) worth a full-distance
@@ -144,13 +155,15 @@ class FrontierIndex:
                ) -> Optional[IndexEntry]:
         """The entry whose family vector matches ``wl`` elementwise within
         ``match_rtol`` (and zero absolute tolerance — a family with a zero
-        component only matches an exact zero), or ``None``.  A JSON
+        component only matches an exact zero; a key with a census share
+        only matches one with a share), or ``None``.  A JSON
         round-trip preserves float64 exactly, so workloads that built the
         index always hit."""
         key = family_key(wl)
         for i in self._window(key):
             e = self.entries[i]
-            if np.allclose(e.family, key, rtol=match_rtol, atol=0.0):
+            if (e.family.shape == key.shape
+                    and np.allclose(e.family, key, rtol=match_rtol, atol=0.0)):
                 return e
         return None
 
