@@ -522,15 +522,16 @@ def telemetry_matrix(workloads, cons) -> tuple:
     inc_cost = _op_cost_s(cal_counter.inc, 50_000)
 
     # what the best instrumented run actually did: spans from its ring,
-    # counter incs from its own counters (one inc per fused launch and per
-    # overflowed workload; candidates + tiles_total per tile; one per
-    # checkpoint)
+    # counter incs from its own counters (one inc per fused launch, per
+    # overflowed workload and per copy of a launch's full rows; candidates
+    # + tiles_total per tile; one per checkpoint)
     n_spans_run = len(instr_tel.tracer.records)
     tiles = instr_tel.counter("campaign_tiles_total").value
     launches = instr_tel.counter("evaluator_fused_launches_total").value
     overflows = instr_tel.counter("evaluator_overflows_total").value
+    full_fetches = instr_tel.counter("evaluator_full_fetches_total").value
     ckpts = instr_tel.counter("campaign_checkpoint_writes_total").value
-    counter_ops = launches + overflows + 2 * tiles + ckpts
+    counter_ops = launches + overflows + full_fetches + 2 * tiles + ckpts
     attributed_s = n_spans_run * span_cost + counter_ops * inc_cost
     overhead = attributed_s / instr.sweep_wall_s
 

@@ -113,7 +113,8 @@ def check_compiled_kernel(cfg: CampaignConfig, n_workloads: int) -> None:
     check(not ops.default_interpret(), "the Pallas sweep would be interpreted")
     cons = cfg.resolved_constraint
     fn = dse_sweep._jit_dse_sweep(cfg.sim, cons.max_power_w,
-                                  cons.max_latency_s, cons.min_hbm_fit, False)
+                                  cons.max_latency_s, cons.min_hbm_fit, False,
+                                  cfg.max_survivors)
     lanes = dse_sweep.padded_lanes(CHUNK, n_workloads)
     text = fn.lower(
         jax.ShapeDtypeStruct((len(dse_sweep.CAND_COLS), lanes), np.float32),

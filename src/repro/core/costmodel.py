@@ -552,43 +552,79 @@ def _screen_rows(energy, latency, feasible):
     return jax.vmap(row)(energy, latency, feasible)
 
 
-def _compact_rows_host(keep, energy, latency, max_survivors: int):
-    """numpy survivor compaction of screened [W, N] rows: (surv_idx, surv_e,
-    surv_l) as [W, K] with ascending lanes, rows past the row's survivor
-    count zero-filled.  Both fused evaluators compact here, on every
-    backend, from the full rows fetched to the host; ``_compact_rows_device``
-    is the same contract in jnp, which no evaluator calls yet."""
-    w_count, n = keep.shape
-    k = min(int(max_survivors), n)
-    surv_idx = np.zeros((w_count, k), np.int64)
-    surv_e = np.zeros((w_count, k), energy.dtype)
-    surv_l = np.zeros((w_count, k), latency.dtype)
-    for w in range(w_count):
-        pos = np.flatnonzero(keep[w])[:k]
-        surv_idx[w, :pos.size] = pos
-        surv_e[w, :pos.size] = energy[w, pos]
-        surv_l[w, :pos.size] = latency[w, pos]
-    return surv_idx, surv_e, surv_l
+# lanes per block of the survivor compaction (``_compact_rows_device``); a
+# v5e compacts faster with 128, a CPU in float64 several times slower
+_COMPACT_BLOCK = 64
 
 
 def _compact_rows_device(keep, energy, latency, max_survivors: int):
-    """jnp survivor compaction (cumsum-rank scatter) for compiled backends,
-    same contract as ``_compact_rows_host``."""
+    """jnp survivor compaction of screened [W, N] rows: (surv_idx, surv_e,
+    surv_l) as [W, K], ``K = min(max_survivors, N)``, with the survivors'
+    lanes in ascending order and energy / latency taken bitwise from the
+    same arrays; entries past a row's survivor count are zero.  A row that
+    overflows keeps its first ``K`` survivors.
+
+    The lanes fall into blocks of ``_COMPACT_BLOCK``.  Survivor slot r
+    takes its block (the number of blocks that end with fewer than r
+    survivors) as one row gather of the block's running survivor count
+    and the bits of its energy and latency, and picks the lane whose count
+    is r by a compare: one gather of ``W x K`` rows, where a search and
+    element gathers of energy and latency would make many, and a TPU pays
+    for a gather by its count of indices."""
     import jax
     import jax.numpy as jnp
-    n = keep.shape[1]
+    from jax import lax
+    w, n = keep.shape
     k = min(int(max_survivors), n)
-    lane = jnp.arange(n, dtype=jnp.int32)
+    b = _COMPACT_BLOCK
+    nb = -(-n // b)
+    pad = ((0, 0), (0, nb * b - n))
+    bits = jnp.dtype(f"int{8 * energy.dtype.itemsize}")
+    kp = jnp.pad(keep, pad)
+    count = jnp.cumsum(kp, axis=1, dtype=jnp.int32)   # survivors up to a lane
+    rank = jnp.arange(1, k + 1, dtype=jnp.int32)
+    blk = jnp.sum(count[:, b - 1::b][:, None, :] < rank[None, :, None],
+                  axis=2, dtype=jnp.int32)                          # [W, K]
+    data = jnp.stack([jnp.where(kp, count, 0).astype(bits)] + [
+        lax.bitcast_convert_type(jnp.pad(x, pad), bits)
+        for x in (energy, latency)], axis=1)                  # [W, 3, nb * b]
+    data = data.reshape(w, 3, nb, b).transpose(0, 2, 1, 3)    # [W, nb, 3, b]
+    rows = jax.vmap(lambda d, i: jnp.take(d, i, axis=0, mode="clip"))(
+        data, blk)                                            # [W, K, 3, b]
+    hit = rows[:, :, 0, :] == rank[None, :, None]
+    lane = jnp.arange(b, dtype=jnp.int32)
+    pos = blk * b + jnp.sum(jnp.where(hit, lane, 0), axis=2, dtype=jnp.int32)
+    e_bits, l_bits = (jnp.sum(jnp.where(hit, rows[:, :, c, :], 0), axis=2,
+                              dtype=bits) for c in (1, 2))
+    filled = rank[None, :] <= count[:, -1:]
+    zero = jnp.zeros((), energy.dtype)
+    return (jnp.where(filled, pos, 0),
+            jnp.where(filled, lax.bitcast_convert_type(e_bits, energy.dtype),
+                      zero),
+            jnp.where(filled, lax.bitcast_convert_type(l_bits, latency.dtype),
+                      zero))
 
-    def row(kp, e, l):
-        tgt = jnp.where(kp, jnp.cumsum(kp) - 1, k)
-        pos = jnp.zeros(k, jnp.int32).at[tgt].set(lane, mode="drop")
-        filled = jnp.arange(k) < jnp.sum(kp)
-        return (jnp.where(filled, pos, 0),
-                jnp.where(filled, e[pos], 0.0),
-                jnp.where(filled, l[pos], 0.0))
 
-    return jax.vmap(row)(keep, energy, latency)
+def _reduce_launch(energy, latency, feasible, max_survivors: int):
+    """The in-trace tail both fused launches share: the screen
+    (``_screen_rows``), the survivors compacted on the device
+    (``_compact_rows_device``), and the outputs packed for the host.
+    Returns ``(ints, floats, full)``: int32 ``[W, K + 2]`` (survivor lanes,
+    ``n_surv``, ``n_feas``), ``[W, 2K + 2]`` in the sweep dtype (survivor
+    energy, survivor latency, ``ref_e``, ``ref_l``), and the full rows
+    ``(energy, latency, feasible)``, each ``[W, N]`` as the launch computed
+    them, which stay on the device (``SweepReduced.full``)."""
+    import jax.numpy as jnp
+    keep, n_surv, n_feas, ref_e, ref_l = _screen_rows(energy, latency,
+                                                       feasible)
+    idx, surv_e, surv_l = _compact_rows_device(keep, energy, latency,
+                                               max_survivors)
+    ints = jnp.concatenate(
+        [idx, n_surv[:, None].astype(jnp.int32),
+         n_feas[:, None].astype(jnp.int32)], axis=1)
+    floats = jnp.concatenate(
+        [surv_e, surv_l, ref_e[:, None], ref_l[:, None]], axis=1)
+    return ints, floats, (energy, latency, feasible)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)  # eq=False: ndarray fields
@@ -596,21 +632,21 @@ class SweepReduced:
     """Reduced result of one fused (all-workloads x tile) sweep launch.
 
     ``surv_*`` are the screened tile survivors (a feasible superset of the
-    tile's Pareto skyline) — all a frontier merge needs; ``*_full`` back
-    the (rare) overflow fallback when a workload's screened set exceeds
-    ``max_survivors``."""
+    tile's Pareto skyline), compacted on the device: all a frontier merge
+    needs.  ``full`` holds the launch's full rows, left on the device
+    when compiled; ``TileEvaluator.reduce_tile`` copies them to the host,
+    once, only for a workload whose screened set exceeds
+    ``max_survivors`` or for an adaptive campaign's training sample."""
 
-    surv_idx: np.ndarray         # int [W, K] lane indices into the tile
-    surv_energy: np.ndarray      # [W, K], rows past n_survivors are fill
+    surv_idx: np.ndarray         # int32 [W, K] lane indices into the tile
+    surv_energy: np.ndarray      # [W, K], rows past n_survivors are zero
     surv_latency: np.ndarray     # [W, K]
-    n_survivors: np.ndarray      # int [W] (may exceed K: overflow)
-    n_feasible: np.ndarray       # int [W]
+    n_survivors: np.ndarray      # int32 [W] (may exceed K: overflow)
+    n_feasible: np.ndarray       # int32 [W]
     ref_energy: np.ndarray       # [W] feasible max (-inf if none)
     ref_latency: np.ndarray      # [W]
     max_survivors: int
-    energy_full: object          # [W, N] — read only on overflow fallback
-    latency_full: object
-    feasible_full: object
+    full: tuple                  # (energy, latency, feasible) [W, N] each
 
     def overflowed(self, w: int) -> bool:
         return int(self.n_survivors[w]) > self.max_survivors
@@ -618,7 +654,7 @@ class SweepReduced:
 
 @functools.lru_cache(maxsize=None)
 def _jit_sweep_reduced(sim: SimConfig, max_power_w, max_latency_s,
-                       min_hbm_fit: bool):
+                       min_hbm_fit: bool, max_survivors: int):
     import jax
     import jax.numpy as jnp
 
@@ -641,7 +677,7 @@ def _jit_sweep_reduced(sim: SimConfig, max_power_w, max_latency_s,
             max_power_w, max_latency_s, min_hbm_fit, xp=jnp)
         e = jnp.broadcast_to(b.energy_j, feas.shape)
         l = jnp.broadcast_to(b.latency_s, feas.shape)
-        return _screen_rows(e, l, feas) + (e, l, feas)
+        return _reduce_launch(e, l, feas, max_survivors)
 
     return jax.jit(run)
 
@@ -659,7 +695,8 @@ def sweep_workloads_reduced_jit(wl_cols, chip_cols: Dict, n_chips, freq_mhz,
     census scaling, topology-aware simulation, constraint masking and the
     per-tile skyline pre-reduction (a conservative dominance screen whose
     survivors are a guaranteed superset of the tile's feasible Pareto set)
-    all happen in-trace — so the host only handles O(survivors) per tile.
+    and the survivors' compaction all happen in-trace — so the host copies
+    and handles O(survivors) per tile.
     float32 under the repo's default x64-disabled config (the ``"jit"``
     precision tier); the Pallas kernel path (``repro.kernels.dse_sweep``)
     shares every helper and runs float64 in interpret mode.  ``chip_cols``
@@ -673,7 +710,7 @@ def sweep_workloads_reduced_jit(wl_cols, chip_cols: Dict, n_chips, freq_mhz,
         raise ValueError(f"wl_cols must be [W, {len(WL_COLS)}] ({WL_COLS})")
     cols = {k: chip_cols[k] for k in SWEEP_GATHER_FIELDS}
     fn = _jit_sweep_reduced(sim, max_power_w, max_latency_s,
-                            bool(min_hbm_fit))
+                            bool(min_hbm_fit), int(max_survivors))
     return run_reduced_launch(
         fn, (np.asarray(wl_cols, np.float64), cols, n_chips, freq_mhz,
              mesh_pod, mesh_data, mesh_model, valid),
@@ -684,40 +721,36 @@ def run_reduced_launch(fn, args, max_survivors: int,
                        tracer=NULL_TRACER) -> SweepReduced:
     """The host stages of one fused launch, shared by both fused evaluators:
     ``dispatch`` (``fn(*args)`` returns its futures), ``device_wait`` (the
-    host waits for the chip), ``fetch`` (every output to the host) and
-    ``host_compact`` (``build_sweep_reduced``), each a span of ``tracer``.
-    The wait is the one a fetch makes anyway: a tracing ``tracer`` takes it
-    first, so that it is timed apart from the copies; untraced, the first
-    copy waits."""
+    host waits for the chip), ``fetch`` (the two small outputs of
+    ``_reduce_launch`` to the host in one ``jax.device_get``) and
+    ``host_compact`` (``build_sweep_reduced``: slicing), each a span of
+    ``tracer``.  The full rows stay on the device.  The wait is the one a
+    fetch makes anyway: a tracing ``tracer`` takes it first, so that it is
+    timed apart from the copies; untraced, the copy waits."""
+    import jax
     with tracer.span("dispatch"):
-        out = fn(*args)
+        ints, floats, full = fn(*args)
     if tracer.tracing:
-        import jax
         with tracer.span("device_wait"):
-            jax.block_until_ready(out)
+            jax.block_until_ready((ints, floats, full))
     with tracer.span("fetch"):
-        out = tuple(np.asarray(o) for o in out)
+        ints, floats = jax.device_get((ints, floats))
     with tracer.span("host_compact"):
-        return build_sweep_reduced(out, max_survivors)
+        return build_sweep_reduced((ints, floats, full), max_survivors)
 
 
 def build_sweep_reduced(out, max_survivors: int) -> SweepReduced:
-    """Assemble the host-side ``SweepReduced`` from a fused launch's output
-    tuple (keep, n_surv, n_feas, ref_e, ref_l, e_full, l_full, feas_full),
-    fetched to the host.  Survivors are compacted in numpy
-    (``_compact_rows_host``); the full rows stay for the overflow fallback.
-    """
-    keep = np.asarray(out[0])
-    e_full, l_full = np.asarray(out[5]), np.asarray(out[6])
-    surv_idx, surv_e, surv_l = _compact_rows_host(
-        keep, e_full, l_full, max_survivors)
+    """Assemble the ``SweepReduced`` of a fused launch from its outputs
+    ``(ints, floats, full)`` (``_reduce_launch``): the first two fetched
+    to the host and sliced here, ``full`` the device rows, kept unread."""
+    ints, floats, full = out
+    k = ints.shape[1] - 2
     return SweepReduced(
-        surv_idx=surv_idx, surv_energy=surv_e, surv_latency=surv_l,
-        n_survivors=np.asarray(out[1]), n_feasible=np.asarray(out[2]),
-        ref_energy=np.asarray(out[3]), ref_latency=np.asarray(out[4]),
-        max_survivors=int(max_survivors),
-        energy_full=e_full, latency_full=l_full,
-        feasible_full=np.asarray(out[7]))
+        surv_idx=ints[:, :k], surv_energy=floats[:, :k],
+        surv_latency=floats[:, k:2 * k],
+        n_survivors=ints[:, k], n_feasible=ints[:, k + 1],
+        ref_energy=floats[:, 2 * k], ref_latency=floats[:, 2 * k + 1],
+        max_survivors=int(max_survivors), full=full)
 
 
 @functools.lru_cache(maxsize=None)

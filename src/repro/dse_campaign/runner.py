@@ -14,8 +14,9 @@ a ``SpaceSpec``, tile by tile.  Two tile engines exist:
   for the whole sweep, and ALL workloads are evaluated in a single launch
   per tile (``costmodel.sweep_workloads_reduced_jit`` or the Pallas
   DSE-sweep kernel).  The launch also reduces each workload's tile to its
-  feasible Pareto survivors on device, so the host transfers O(survivors)
-  instead of O(tile) and merges via ``StreamingFrontier.merge_reduced``
+  feasible Pareto survivors, compacted on device, so the host transfers
+  O(survivors) instead of O(tile) (the full rows follow only for a
+  workload that overflowed) and merges via ``StreamingFrontier.merge_reduced``
   (proven identical to the raw merge); ``Candidate`` objects are
   materialized lazily for survivors only.  A prefetch thread stages the
   next tile's arrays while the device evaluates the current one
@@ -324,6 +325,8 @@ class TileEvaluator:
                             for wl in self.workloads)
         self._c_overflows = self.telemetry.counter(
             "evaluator_overflows_total")
+        self._c_full_fetches = self.telemetry.counter(
+            "evaluator_full_fetches_total")
 
     @property
     def fused_launches(self) -> int:
@@ -494,6 +497,15 @@ class TileEvaluator:
         return (lo + loc.astype(np.int64), e[loc], l[loc], n_feas,
                 ref_e, ref_l)
 
+    def _fetch_full(self, red: costmodel.SweepReduced) -> tuple:
+        """``red``'s full rows ``(energy, latency, feasible)`` copied to the
+        host in one ``jax.device_get`` (span ``fetch_full``, counter
+        ``evaluator_full_fetches_total``)."""
+        import jax
+        self._c_full_fetches.inc()
+        with self.telemetry.span("fetch_full"):
+            return jax.device_get(red.full)
+
     def reduce_tile(self, batch: dse.CandidateBatch, lo: int,
                     stage: Optional[Callable[[], Any]] = None
                     ) -> TileReduction:
@@ -510,9 +522,11 @@ class TileEvaluator:
 
         With ``config.adaptive`` set, the reduction additionally carries a
         seeded per-tile training subsample (see ``TileReduction``); the
-        fused path reads it off the already-materialized full rows, the
-        per-workload path off each workload's evaluation — zero extra
-        launches either way.
+        fused path reads it off the launch's full rows, the per-workload
+        path off each workload's evaluation — zero extra launches either
+        way.  The fused path copies the full rows to the host (span
+        ``fetch_full``, counter ``evaluator_full_fetches_total``) at most
+        once per launch, and only for an overflow or a sample.
 
         ``stage`` hands ``sweep_reduced`` the tile as ``stage_tile``
         staged it (Pallas only).
@@ -533,20 +547,19 @@ class TileEvaluator:
 
         if self.fused:
             red = self.sweep_reduced(batch, stage)
+            full = None            # red.full on the host, once it is read
             with self.telemetry.span("compact", n=n):
                 for wi in range(len(self.workloads)):
                     if lidx is not None:
-                        samp_e.append(np.asarray(
-                            red.energy_full, np.float64)[wi][lidx])
-                        samp_l.append(np.asarray(
-                            red.latency_full, np.float64)[wi][lidx])
+                        full = full or self._fetch_full(red)
+                        samp_e.append(full[0][wi][lidx].astype(np.float64))
+                        samp_l.append(full[1][wi][lidx].astype(np.float64))
                     if red.overflowed(wi):
                         self._c_overflows.inc()
                         with self.telemetry.span("overflow_reduce", wl=wi):
+                            full = full or self._fetch_full(red)
                             add(*self._reduce_rows(
-                                np.asarray(red.energy_full)[wi][:n],
-                                np.asarray(red.latency_full)[wi][:n],
-                                np.asarray(red.feasible_full)[wi][:n], lo))
+                                *(r[wi][:n] for r in full), lo))
                         continue
                     k = int(red.n_survivors[wi])
                     nf = int(red.n_feasible[wi])

@@ -25,12 +25,14 @@ frontier holds the float64 numpy evaluator's exact candidate set (values
 agree to ~1 ulp — XLA fusion noise only); compiled on an accelerator it
 runs float32 (the same tier as ``simulate_batch_jit``, ~1e-6 relative).
 
-The jitted wrapper fuses the per-tile skyline pre-reduction
-(``costmodel._screen_rows`` — a conservative dominance screen whose
-survivors are a guaranteed superset of the tile's feasible Pareto set)
-behind the kernel, so the frontier merge only ever handles O(survivors)
-per tile — the same ``SweepReduced`` contract as the jit reference path
-``costmodel.sweep_workloads_reduced_jit``.
+The jitted wrapper fuses the per-tile skyline pre-reduction behind the
+kernel (``costmodel._reduce_launch``: the screen ``_screen_rows`` — a
+conservative dominance screen whose survivors are a guaranteed superset of
+the tile's feasible Pareto set — then the survivors compacted on the
+device), so one launch copies O(survivors) per tile to the host and the
+full [W, N] rows stay on the device, read only where a workload overflows
+``max_survivors`` — the same ``SweepReduced`` contract as the jit
+reference path ``costmodel.sweep_workloads_reduced_jit``.
 """
 
 from __future__ import annotations
@@ -129,14 +131,13 @@ def dse_sweep_pallas(cand_cols, wl_cols, *, sim: costmodel.SimConfig,
 
 @functools.lru_cache(maxsize=None)
 def _jit_dse_sweep(sim: costmodel.SimConfig, max_power_w, max_latency_s,
-                   min_hbm_fit: bool, interpret: bool):
+                   min_hbm_fit: bool, interpret: bool, max_survivors: int):
     def run(cand_cols, wl_cols):
         e, l, f = dse_sweep_pallas(
             cand_cols, wl_cols, sim=sim, max_power_w=max_power_w,
             max_latency_s=max_latency_s, min_hbm_fit=min_hbm_fit,
             interpret=interpret)
-        feas = f > 0
-        return costmodel._screen_rows(e, l, feas) + (e, l, feas)
+        return costmodel._reduce_launch(e, l, f > 0, max_survivors)
 
     return jax.jit(run)
 
@@ -208,7 +209,7 @@ def dse_sweep_staged(stage: Callable[[], Any], wl_cols: np.ndarray, *,
         if not interpret:
             wl_cols = wl_cols.astype(np.float32)
     fn = _jit_dse_sweep(sim, max_power_w, max_latency_s, bool(min_hbm_fit),
-                        bool(interpret))
+                        bool(interpret), int(max_survivors))
     with (jax.enable_x64(True) if interpret else contextlib.nullcontext()):
         return costmodel.run_reduced_launch(fn, (cand_cols, wl_cols),
                                             int(max_survivors), tracer)

@@ -67,20 +67,24 @@ def _sds(sharding, shape, dtype=jnp.float32):
     (FUSED_CHUNK, 20),
 ])
 def test_pallas_sweep_compiles_for_v5e(one_chip, tile, n_workloads):
-    """The fused Pallas sweep plus its on-device screen, float32 as the
-    campaign runs it on the chip, lowers to a Mosaic kernel."""
+    """The fused Pallas sweep plus its on-device screen and compaction,
+    float32 as the campaign runs it on the chip, lowers to a Mosaic kernel;
+    the compaction gathers block rows and scatters nothing."""
     lanes = dse_sweep.padded_lanes(tile, n_workloads)
     fn = dse_sweep._jit_dse_sweep(costmodel.SimConfig(), None, None, True,
-                                  False)
+                                  False, 2048)
     compiled = fn.lower(
         _sds(one_chip, (len(dse_sweep.CAND_COLS), lanes)),
         _sds(one_chip, (n_workloads, len(costmodel.WL_COLS)))).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert " gather(" in text and " scatter(" not in text
 
 
 def test_fused_jit_sweep_compiles_for_v5e(one_chip):
     """The fused jit sweep (plain XLA, no kernel) at the campaign tile."""
-    fn = costmodel._jit_sweep_reduced(costmodel.SimConfig(), None, None, True)
+    fn = costmodel._jit_sweep_reduced(costmodel.SimConfig(), None, None,
+                                      True, 2048)
     row = _sds(one_chip, (FUSED_CHUNK,))
     compiled = fn.lower(
         _sds(one_chip, (N_CI_WORKLOADS, len(costmodel.WL_COLS))),

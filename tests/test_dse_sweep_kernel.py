@@ -83,9 +83,7 @@ def test_kernel_matches_scalar_oracle_over_chunks(chunk):
         hi = min(lo + chunk, n)
         red, _ = sweep_tile(spec, WLS, lo, hi)
         for wi, (o_e, o_l, o_f) in enumerate(oracles):
-            e = np.asarray(red.energy_full)[wi][:hi - lo]
-            l = np.asarray(red.latency_full)[wi][:hi - lo]
-            f = np.asarray(red.feasible_full)[wi][:hi - lo]
+            e, l, f = (np.asarray(r)[wi, :hi - lo] for r in red.full)
             np.testing.assert_allclose(e, o_e[lo:hi], rtol=1e-6)
             np.testing.assert_allclose(l, o_l[lo:hi], rtol=1e-6)
             np.testing.assert_array_equal(f, o_f[lo:hi])
@@ -123,7 +121,7 @@ def test_kernel_matches_oracle_constraint_branches(cons):
     assert 0 < o_f.sum() < len(spec)            # the mask actually bites
     red, _ = sweep_tile(spec, [wl], 0, len(spec), cons=cons)
     np.testing.assert_array_equal(
-        np.asarray(red.feasible_full)[0][:len(spec)], o_f)
+        np.asarray(red.full[2])[0, :len(spec)], o_f)
     assert int(red.n_feasible[0]) == int(o_f.sum())
 
 
@@ -134,7 +132,7 @@ def test_all_infeasible_tile():
     red, batch = sweep_tile(spec, WLS[:1], 0, len(spec), cons=cons)
     assert int(red.n_feasible[0]) == 0
     assert int(red.n_survivors[0]) == 0
-    assert not np.asarray(red.feasible_full)[0].any()
+    assert not np.asarray(red.full[2])[0].any()
     fr = StreamingFrontier()
     fr.merge_reduced([], [], [], [], span=(0, len(batch)), n_feasible=0,
                      tile=0)
@@ -249,24 +247,175 @@ def test_staged_launch_equals_one_call(n_workloads, lo, hi):
         jit.sweep_reduced(batch, lambda: staged)
 
 
-def test_compact_rows_device_matches_host():
-    """The compiled-backend compaction and the host compaction agree."""
-    import jax.numpy as jnp
+def _compact_rows_host(keep, energy, latency, max_survivors: int):
+    """numpy oracle of the survivor compaction: (surv_idx, surv_e, surv_l)
+    as [W, K] with ascending lanes, entries past the row's survivor count
+    zero, an overflowing row's first K survivors kept."""
+    w_count, n = keep.shape
+    k = min(int(max_survivors), n)
+    surv_idx = np.zeros((w_count, k), np.int64)
+    surv_e = np.zeros((w_count, k), energy.dtype)
+    surv_l = np.zeros((w_count, k), latency.dtype)
+    for w in range(w_count):
+        pos = np.flatnonzero(keep[w])[:k]
+        surv_idx[w, :pos.size] = pos
+        surv_e[w, :pos.size] = energy[w, pos]
+        surv_l[w, :pos.size] = latency[w, pos]
+    return surv_idx, surv_e, surv_l
+
+
+# rows of several compaction blocks, the last one part padding
+COMPACT_K, COMPACT_N = 40, 300
+assert COMPACT_N % costmodel._COMPACT_BLOCK and COMPACT_N > 3 * costmodel._COMPACT_BLOCK
+
+
+def _keep_case(case):
+    """[3, COMPACT_N] survivor masks of one compaction edge case."""
     rng = np.random.default_rng(7)
-    keep = rng.random((3, 64)) < 0.2
-    e = rng.random((3, 64))
-    l = rng.random((3, 64))
-    hi, he, hl = costmodel._compact_rows_host(keep, e, l, 16)
-    di, de, dl = costmodel._compact_rows_device(
-        jnp.asarray(keep), jnp.asarray(e, jnp.float32),
-        jnp.asarray(l, jnp.float32), 16)
-    for w in range(3):
-        k = int(keep[w].sum())
-        np.testing.assert_array_equal(hi[w][:k], np.asarray(di)[w][:k])
-        np.testing.assert_allclose(he[w][:k], np.asarray(de)[w][:k],
-                                   rtol=1e-6)
-        np.testing.assert_allclose(hl[w][:k], np.asarray(dl)[w][:k],
-                                   rtol=1e-6)
+    w, n, k = 3, COMPACT_N, COMPACT_K
+    if case == "random":
+        return rng.random((w, n)) < 0.1
+    keep = np.zeros((w, n), bool)
+    count = {"none": 0, "exactly_k": k, "k_plus_1": k + 1, "all": n}.get(case)
+    for r in range(w):
+        if case == "padded":       # lanes past 250 are padding
+            keep[r, :250] = rng.random(250) < 0.15
+        else:
+            keep[r, rng.choice(n, size=count, replace=False)] = True
+    return keep
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+@pytest.mark.parametrize("case", ["random", "none", "exactly_k", "k_plus_1",
+                                  "all", "padded"])
+def test_compact_rows_device_matches_host(case, dtype):
+    """The device compaction equals the numpy oracle bitwise, in float32 and
+    in float64 (interpret mode's precision): alone, and inside the jitted
+    launch tail (``_reduce_launch``) the fused launches run.  The rows lie on
+    one anti-diagonal (energy rising, latency falling), so the screen drops
+    no feasible lane and the survivors are the mask itself;
+    ``n_survivors`` reports the true count past ``K``."""
+    import functools
+    import jax
+    keep = _keep_case(case)
+    rng = np.random.default_rng(11)
+    e = np.sort(rng.random(keep.shape) + 0.5, axis=1)
+    l = np.sort(rng.random(keep.shape) + 0.5, axis=1)[:, ::-1]
+    if case == "padded":           # padding lanes copy lane 0
+        e[:, 250:], l[:, 250:] = e[:, :1], l[:, :1]
+    e, l = e.astype(dtype), np.ascontiguousarray(l).astype(dtype)
+    k = COMPACT_K
+    with jax.enable_x64(dtype == "float64"):
+        forms = [jax.jit(costmodel._compact_rows_device,
+                         static_argnums=3)(keep, e, l, k)]
+        red = costmodel.run_reduced_launch(
+            jax.jit(functools.partial(costmodel._reduce_launch,
+                                      max_survivors=k)), (e, l, keep), k)
+    hi, he, hl = _compact_rows_host(keep, e, l, k)
+    for got_i, got_e, got_l in forms + [(red.surv_idx, red.surv_energy,
+                                         red.surv_latency)]:
+        got_i, got_e, got_l = map(np.asarray, (got_i, got_e, got_l))
+        assert got_e.dtype == got_l.dtype == np.dtype(dtype)
+        np.testing.assert_array_equal(got_i, hi)
+        assert np.array_equal(got_e, he) and np.array_equal(got_l, hl)
+    counts = keep.sum(axis=1)
+    np.testing.assert_array_equal(red.n_survivors, counts)
+    np.testing.assert_array_equal(red.n_feasible, counts)
+    assert [red.overflowed(w) for w in range(3)] == list(counts > k)
+    ref_e = np.where(keep, e, -np.inf).max(axis=1)
+    assert np.array_equal(red.ref_energy, ref_e)
+    for got, want in zip(red.full, (e, l, keep)):
+        assert np.array_equal(np.asarray(got), want)
+
+
+def test_launch_survivors_are_gathered_from_its_full_rows():
+    """On a real fused launch (interpret mode): the survivors are ascending
+    feasible lanes, their energy and latency are the full rows' values at
+    those lanes bitwise, and the fill past ``n_survivors`` is zero."""
+    spec = small_spec(chip_counts=(16, 64), freq_points=20)
+    red, _ = sweep_tile(spec, WLS, 0, len(spec), max_survivors=32)
+    import jax
+    full = jax.device_get(red.full)
+    for wi in range(len(WLS)):
+        k = min(int(red.n_survivors[wi]), red.surv_idx.shape[1])
+        idx = red.surv_idx[wi][:k]
+        assert k > 0 and np.all(np.diff(idx) > 0)
+        assert full[2][wi, idx].all()
+        assert np.array_equal(red.surv_energy[wi][:k], full[0][wi, idx])
+        assert np.array_equal(red.surv_latency[wi][:k], full[1][wi, idx])
+        assert not red.surv_idx[wi][k:].any()
+        assert not red.surv_energy[wi][k:].any()
+
+
+def _counting_launches(monkeypatch):
+    """Record, per fused launch, whether any workload overflowed."""
+    real = TileEvaluator.sweep_reduced
+    launches = []
+
+    def sweep(self, batch, stage=None):
+        red = real(self, batch, stage)
+        launches.append(any(red.overflowed(w)
+                            for w in range(len(red.n_survivors))))
+        return red
+    monkeypatch.setattr(TileEvaluator, "sweep_reduced", sweep)
+    return launches
+
+
+@pytest.mark.parametrize("max_survivors", [2048, 16])
+def test_full_rows_copied_only_for_an_overflowed_launch(max_survivors,
+                                                        monkeypatch):
+    """A Pallas campaign (interpret) copies a launch's full rows once for
+    each launch with an overflowed workload and never otherwise; with a
+    tiny ``max_survivors`` its frontiers still equal the numpy
+    evaluator's."""
+    from repro.telemetry import Telemetry
+    launches = _counting_launches(monkeypatch)
+    tel = Telemetry()
+    spec = small_spec(chip_counts=(16, 64), freq_points=20, chunk_size=32)
+    cfg = dict(space=spec, constraint=CONS)
+    got = Campaign(WLS, CampaignConfig(evaluator="pallas",
+                                       max_survivors=max_survivors, **cfg),
+                   telemetry=tel).run()
+    fetches = tel.counter("evaluator_full_fetches_total").value
+    assert fetches == sum(launches)
+    fetch_spans = [r for r in tel.tracer.records if r.name == "fetch_full"]
+    assert len(fetch_spans) == fetches
+    if max_survivors == 2048:
+        assert fetches == 0
+    else:
+        assert 0 < fetches < len(launches)
+        want = Campaign(WLS, CampaignConfig(evaluator="numpy", **cfg)).run()
+        for key in want.frontiers:
+            assert_same_candidate_set(want.frontiers[key],
+                                      got.frontiers[key], rtol=1e-12)
+
+
+def test_adaptive_samples_read_from_one_full_copy(monkeypatch):
+    """An adaptive campaign's training samples are the launch's full rows
+    at the sampled lanes, bitwise, read from one host copy per launch."""
+    import jax
+    from repro.dse_campaign import AdaptiveConfig
+    from repro.telemetry import Telemetry
+    spec = small_spec(chunk_size=32)
+    tel = Telemetry()
+    ev = TileEvaluator(WLS, CampaignConfig(
+        space=spec, constraint=CONS, evaluator="pallas",
+        adaptive=AdaptiveConfig(train_sample=8)), telemetry=tel)
+    reds = []
+    real = ev.sweep_reduced
+    monkeypatch.setattr(ev, "sweep_reduced",
+                        lambda *a: reds.append(real(*a)) or reds[-1])
+    batch = spec.slice(0, 32, with_candidates=False)
+    tr = ev.reduce_tile(batch, 0)
+    assert tel.counter("evaluator_full_fetches_total").value == 1
+    full = jax.device_get(reds[0].full)
+    lidx = tr.sample_lidx
+    assert len(lidx) == 8
+    for wi in range(len(WLS)):
+        for got, row in ((tr.sample_energy[wi], full[0][wi]),
+                         (tr.sample_latency[wi], full[1][wi])):
+            assert got.dtype == np.float64
+            assert np.array_equal(got, np.asarray(row, np.float64)[lidx])
 
 
 # --- campaign-level identity --------------------------------------------------

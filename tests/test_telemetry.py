@@ -309,7 +309,8 @@ class TestInstrumentationIsAReading:
 LAUNCH_STAGES = {"dispatch": "launch", "device_wait": "launch",
                  "fetch": "launch", "host_compact": "launch"}
 PALLAS_STAGES = {"pack": "launch"}
-CAMPAIGN_STAGES = {"overflow_reduce": "compact", "materialize": "merge",
+CAMPAIGN_STAGES = {"overflow_reduce": "compact",
+                   "fetch_full": "overflow_reduce", "materialize": "merge",
                    "fold": "merge", "snapshot": "merge", "tile_wait": None,
                    "tile_slice": None, "lower": None, "compile": None}
 # a constraint of its own per evaluator, so that its sweep compiles afresh
@@ -355,6 +356,10 @@ class TestLaunchStageSpans:
         # every overflowed workload has its span and its counter increment
         overflows = tel.counter("evaluator_overflows_total").value
         assert counts["overflow_reduce"] == overflows > 0
+        # one copy of the full rows per launch that overflowed, at most
+        assert (0 < counts["fetch_full"]
+                == tel.counter("evaluator_full_fetches_total").value
+                <= min(n_tiles, overflows))
         assert {r.attrs["fun_name"] for r in records if r.name == "lower"}
         slices = [r for r in records if r.name == "tile_slice"]
         main = {r.thread_id for r in records if r.name == "tile_eval"}
